@@ -23,6 +23,9 @@ def main() -> None:
     args = p.parse_args()
     only = set(args.only.split(",")) if args.only else set(SUITES)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     t0 = time.time()
     if "table1" in only:
         from benchmarks import paper_table1

@@ -68,7 +68,7 @@ def _serving_run(cfg, data, *, cycles, queries_per_eval, use_kernel=False,
 
     X, y, Xt, yt = data
     srv = GossipServer(batch_size=BATCH, policy="uniform",
-                       use_kernel=use_kernel)
+                       use_kernel=use_kernel, interpret=use_kernel)
     qrng = np.random.default_rng(17)
     labels = []
 

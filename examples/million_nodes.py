@@ -97,6 +97,9 @@ def main() -> None:
     scenario = args.scenario or ("extreme" if args.extreme else "clean")
     scenario = SCENARIO_ALIASES.get(scenario, scenario)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.gossip_linear import (GossipLinearConfig,
                                              with_failure_scenario)
     from repro.core.simulation import message_wire_bytes, run_simulation

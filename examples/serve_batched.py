@@ -56,8 +56,11 @@ def main() -> None:
                          "query")
     ap.add_argument("--use-kernel", action="store_true",
                     help="answer with the fused Pallas voted_predict_batched "
-                         "path (interpret mode off-TPU) instead of the jnp "
-                         "einsum path; answers are bitwise identical")
+                         "path instead of the jnp einsum path; answers are "
+                         "bitwise identical")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernel in interpret mode (for a "
+                         "machine without a TPU)")
     ap.add_argument("--trace", metavar="OUT.json", default=None,
                     help="arm telemetry across the protocol AND the server "
                          "(bitwise invisible): print the per-phase span "
@@ -66,6 +69,9 @@ def main() -> None:
                          "and export a Chrome trace to this path")
     args = ap.parse_args()
     scenario = SCENARIO_ALIASES.get(args.scenario, args.scenario)
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs.gossip_linear import (GossipLinearConfig,
                                              with_failure_scenario)
@@ -91,7 +97,8 @@ def main() -> None:
         from repro.core.telemetry import Telemetry
         tel = Telemetry(label=f"serve_batched N={n} {scenario}")
     srv = GossipServer(batch_size=args.batch, policy=args.policy,
-                       use_kernel=args.use_kernel, telemetry=tel)
+                       use_kernel=args.use_kernel,
+                       interpret=args.interpret, telemetry=tel)
     qrng = np.random.default_rng(7)
     labels = []
 

@@ -156,15 +156,15 @@ def fault_key(key):
 
 
 def corrupt_model(fault: FaultModel, byz, key, w, t, old_w=None, old_t=None,
-                  rows=None, n_total: Optional[int] = None):
+                  rows=None):
     """Apply a model-kind fault on the Byzantine rows of a send batch.
 
     ``w``: (m, d) f32 models about to be transmitted; ``t``: (m,) int32
     counters; ``byz``: (m,) bool. ``old_w``/``old_t`` are the stale models
     (``cache.cache_oldest``), required by ``stale_replay`` only. ``key`` is
     the per-cycle ``fault_key``; ``random_payload`` draws its noise from it
-    — dense callers (m == n_total) leave ``rows=None`` and draw the full
-    ``(n_total, d)`` uniform block, subset callers pass the global row ids
+    — dense callers (m == n) leave ``rows=None`` and draw the full
+    ``(n, d)`` uniform block, subset callers pass the global row ids
     so ``sr_noise_for_rows`` regenerates the identical values at those
     positions (bitwise — the compact_all parity mechanism)."""
     name = fault.name
@@ -179,7 +179,7 @@ def corrupt_model(fault: FaultModel, byz, key, w, t, old_w=None, old_t=None,
         if rows is None:
             u = jax.random.uniform(key, w.shape)
         else:
-            u = sr_noise_for_rows(key, rows, w.shape[-1], n_total)
+            u = sr_noise_for_rows(key, rows, w.shape[-1])
         cw, ct = (2.0 * u - 1.0) * scale, t
     elif name == "stale_replay":
         cw, ct = old_w, old_t
@@ -188,8 +188,7 @@ def corrupt_model(fault: FaultModel, byz, key, w, t, old_w=None, old_t=None,
     return (jnp.where(byz[:, None], cw, w), jnp.where(byz, ct, t))
 
 
-def bitflip_payload(byz, key, payload, rows=None,
-                    n_total: Optional[int] = None):
+def bitflip_payload(byz, key, payload, rows=None):
     """Flip ONE uniformly drawn bit in each Byzantine row of an encoded
     payload block — wire-level corruption applied after
     ``WireCodec.encode`` (the sender's EF residual, computed from the
@@ -197,7 +196,7 @@ def bitflip_payload(byz, key, payload, rows=None,
     payload dtype by bitcasting to the matching unsigned integer lane.
 
     The bit position comes from one uniform per message; dense callers
-    draw ``uniform(key, (n_total, 1))``, subset callers regenerate the
+    draw ``uniform(key, (n, 1))``, subset callers regenerate the
     same values at their global ``rows`` via ``sr_noise_for_rows`` —
     positionally bitwise-equal, like the "int8_sr" compacted send."""
     m, p = payload.shape
@@ -206,7 +205,7 @@ def bitflip_payload(byz, key, payload, rows=None,
     if rows is None:
         u = jax.random.uniform(key, (m, 1))[:, 0]
     else:
-        u = sr_noise_for_rows(key, rows, 1, n_total)[:, 0]
+        u = sr_noise_for_rows(key, rows, 1)[:, 0]
     bit = jnp.minimum((u * nbits).astype(jnp.uint32), nbits - 1)
     uint = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[itemsize]
     col = (bit // (itemsize * 8)).astype(jnp.int32)
@@ -220,40 +219,41 @@ def bitflip_payload(byz, key, payload, rows=None,
     return jnp.where(byz[:, None], flipped, payload)
 
 
-def apply_defense(defense: str, msg_w, valid, recv_w, real=None):
+def apply_defense(defense: str, msg_w, valid, recv_w, axis: int = -1):
     """Screen one receive round's payloads against the receiver's state.
 
     ``msg_w``: (m, d) decoded f32 payloads; ``valid``: (m,) bool;
     ``recv_w``: (m, d) the receiver's CURRENT lastModel (the round chain's
-    ``prev``). ``real`` (optional, (m, d) bool) masks padded lanes out of
-    the reductions — the Pallas kernel's padded-width contract; quantized
-    decodes leave finite garbage in pad lanes, and zero-masking them keeps
-    the in-kernel sums bitwise equal to the unpadded jnp sums (the same
-    precedent as the ``_pegasos`` margin reduction).
+    ``prev``). ``axis`` is the coefficient axis: the node-minor Pallas
+    receive kernel passes (d, m) payloads with ``axis=0`` and a (1, m)
+    ``valid``; the reductions keep their dimension, so the per-message
+    results broadcast against the payloads either way.
 
     Returns ``(msg_w, valid, gated, clipped)``: the (possibly rescaled)
     payloads, the surviving-valid mask, and per-node bool indicators of a
-    rejected (``gated``) / rescaled (``clipped``) message. ``"none"`` is a
-    static no-op so undefended traces stay structurally identical."""
+    rejected (``gated``) / rescaled (``clipped``) message, all shaped like
+    ``valid``. ``"none"`` is a static no-op so undefended traces stay
+    structurally identical."""
     if defense == "none":
         zeros = jnp.zeros(valid.shape, bool)
         return msg_w, valid, zeros, zeros
-    mm = jnp.where(real, msg_w, 0.0) if real is not None else msg_w
-    rm = jnp.where(real, recv_w, 0.0) if real is not None else recv_w
-    sq = jnp.sum(mm * mm, axis=-1)
-    rn = jnp.sum(rm * rm, axis=-1)
+    red = lambda a: jnp.sum(a, axis=axis, keepdims=True)
+    sq = red(msg_w * msg_w)
+    rn = red(recv_w * recv_w)
     finite = jnp.isfinite(sq)          # NaN/inf anywhere poisons the sum
+    per_msg = lambda a: a.reshape(valid.shape)
     if defense == "norm_clip":
         thr = jnp.maximum(NORM_CLIP_MULT ** 2 * rn, NORM_CLIP_FLOOR ** 2)
         clip = finite & (sq > thr)
         scale = jnp.sqrt(thr / jnp.maximum(sq, 1e-30))
-        msg_w = jnp.where(clip[:, None], msg_w * scale[:, None], msg_w)
+        msg_w = jnp.where(clip, msg_w * scale, msg_w)
+        finite, clip = per_msg(finite), per_msg(clip)
         return (msg_w, valid & finite, valid & ~finite, valid & clip)
     if defense == "cosine_gate":
-        dot = jnp.sum(mm * rm, axis=-1)
+        dot = red(msg_w * recv_w)
         anti = (rn > COSINE_GATE_MIN_NORM ** 2) \
             & (dot < COSINE_GATE_THRESHOLD * jnp.sqrt(sq * rn))
-        reject = ~finite | anti
+        reject = per_msg(~finite | anti)
         return (msg_w, valid & ~reject, valid & reject,
                 jnp.zeros(valid.shape, bool))
     raise ValueError(f"unknown defense {defense!r} "

@@ -33,6 +33,7 @@ serving cannot shift a draw counter and break cross-engine parity.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -101,7 +102,7 @@ def assign_queries(n_queries: int, n_nodes: int, *,
 def serve_fresh(fresh_w, X, assign):
     """PREDICT for a query batch: sign of <w_freshest, x> at the assigned
     node — op-for-op the gathered form of ``cache.predict_fresh``."""
-    w = fresh_w[assign]                          # (M, d)
+    w = cache_mod.take_nodes(fresh_w, assign)   # (M, d)
     return jnp.where(jnp.einsum("md,md->m", w, X) >= 0, 1.0, -1.0)
 
 
@@ -115,8 +116,8 @@ def serve_voted(w, count, X, assign):
     (N, C, d) snapshot cache; ``count``: (N,); ``X``: (M, d); ``assign``:
     (M,) int32. Returns (M,) ±1 predictions."""
     c = w.shape[1]
-    w_sel = w[assign]                            # (M, C, d)
-    cnt = count[assign]                          # (M,)
+    w_sel = cache_mod.take_nodes(w, assign)      # (M, C, d)
+    cnt = cache_mod.take_nodes(count, assign)    # (M,)
     scores = jnp.einsum("mcd,md->mc", w_sel, X)
     votes = (scores >= 0).astype(jnp.float32)
     valid = (jnp.arange(c)[None, :] < cnt[:, None]).astype(jnp.float32)
@@ -124,14 +125,14 @@ def serve_voted(w, count, X, assign):
     return jnp.where(p_ratio - 0.5 >= 0, 1.0, -1.0)
 
 
-@jax.jit
-def serve_voted_kernel(w, count, X, assign):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def serve_voted_kernel(w, count, X, assign, *, interpret: bool = False):
     """VOTEDPREDICT for a query batch — the fused Pallas path
-    (``repro.kernels.voted_predict.voted_predict_batched``, interpret mode
-    on CPU backends): gathers the assigned cache rows, then one VMEM pass
-    scores, votes and reduces. Predictions are bitwise equal to
-    :func:`serve_voted` (tests/test_serving.py + the BENCH_serving.json
-    parity probes)."""
-    interpret = jax.default_backend() != "tpu"
-    return voted_predict_batched(w[assign], count[assign], X,
+    (``repro.kernels.voted_predict.voted_predict_batched``; ``interpret``
+    runs it in Pallas interpret mode, for CPU tests): gathers the assigned
+    cache rows, then one VMEM pass scores, votes and reduces. Predictions
+    are bitwise equal to :func:`serve_voted` (tests/test_serving.py + the
+    BENCH_serving.json parity probes)."""
+    return voted_predict_batched(cache_mod.take_nodes(w, assign),
+                                 cache_mod.take_nodes(count, assign), X,
                                  interpret=interpret)

@@ -25,11 +25,12 @@ network:
 * **node-axis sharding** — the receive application (everything that scales
   with N·d) runs under ``shard_map`` with the node axis split over a device
   mesh, reusing the peer-axis machinery proven in
-  ``gossip_optimizer.gossip_merge``.
+  ``gossip_optimizer.gossip_merge``; messages reach receivers on other
+  shards through an exact ring gather (``_mesh_take_slots``).
 * **fused cycle kernel** — on TPU the receive application lowers to the
   Pallas ``kernels/gossip_cycle.py`` kernel: deliver→merge→update→
-  cache-write in one VMEM-resident pass per node block (interpret mode on
-  CPU for the parity tests).
+  cache-write in one VMEM-resident pass per node block (``interpret=True``
+  for the CPU parity tests).
 * **delivery-proportional compacted rounds** — winner rounds nest
   (round-k receivers ⊆ round-(k-1) receivers), so the router's per-cycle
   receiver lists bound the real work. Per chunk the driver picks the
@@ -88,8 +89,9 @@ from repro.core.learners import LinearModel, make_update
 from repro.core.merge import create_model
 from repro.core import telemetry as telemetry_mod
 from repro.core.simulation import (SimResult, _eval, ef_residual_norm,
-                                   eval_points, message_wire_bytes,
-                                   payload_buffer_bytes, sim_setup)
+                                   eval_points, eval_subset,
+                                   message_wire_bytes, payload_buffer_bytes,
+                                   sim_setup)
 from repro.core.wire_codec import get_codec, sr_noise_for_rows
 from repro.sharding.compat import shard_map_compat
 
@@ -492,14 +494,14 @@ def _pallas_apply(lam: float, interpret: bool, wire, defense: str = "none"):
                  valid, X, y, *, variant, update, msg_scale=None,
                  msg_zp=None):
         del update  # the kernel implements the Pegasos step itself
-        lw, lt, cw, ct, ptr, cnt, gated, clipped = fused_receive_apply(
-            last_w, last_t, cache.w, cache.t, cache.ptr, cache.count,
-            msg_w, msg_t, valid.astype(jnp.int32), X, y,
-            msg_scale=msg_scale, msg_zp=msg_zp, wire=wire,
-            variant=variant, lam=lam, interpret=interpret, defense=defense)
-        new_cache = ModelCache(cw, ct, ptr, cnt)
-        fw, ft = cache_mod.freshest(new_cache)
-        return lw, lt, fw, ft, new_cache, gated, clipped
+        lw, lt, fw, ft, cw, ct, ptr, cnt, gated, clipped = \
+            fused_receive_apply(
+                last_w, last_t, fresh_w, fresh_t, cache.w, cache.t,
+                cache.ptr, cache.count, msg_w, msg_t,
+                valid.astype(jnp.int32), X, y, msg_scale=msg_scale,
+                msg_zp=msg_zp, wire=wire, variant=variant, lam=lam,
+                interpret=interpret, defense=defense)
+        return lw, lt, fw, ft, ModelCache(cw, ct, ptr, cnt), gated, clipped
 
     return apply_fn
 
@@ -540,6 +542,109 @@ def _shard_apply(base_apply, mesh, axis: str):
         return lw2, lt2, fw2, ft2, ModelCache(cw, ct, cp, cc), g2, cl2
 
     return apply_fn
+
+
+def _take_slots(buf, slots):
+    """The messages at flat slot ids (row·N + sender) of a (D, N, ...)
+    buffer lane: ``buf.reshape(D*N, ...)[slots]``."""
+    return buf.reshape((-1,) + buf.shape[2:])[slots]
+
+
+def _as_i32(x):
+    """Payload bits widened to int32 (exact, and inverted by _from_i32)."""
+    if x.dtype.itemsize == 4:
+        return lax.bitcast_convert_type(x, jnp.int32)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = lax.bitcast_convert_type(x, jnp.uint16)
+    return x.astype(jnp.int32)
+
+
+def _from_i32(x, dtype):
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize == 4:
+        return lax.bitcast_convert_type(x, dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return lax.bitcast_convert_type(x.astype(jnp.uint16), dtype)
+    return x.astype(dtype)
+
+
+def _mesh_take_slots(mesh, axis: str):
+    """:func:`_take_slots` for a buffer whose node axis is sharded over
+    ``axis``, with the slot columns sharded the same way.
+
+    Senders and receivers sit on different shards, and a gather with
+    global slot ids from a node-sharded buffer makes XLA all-gather the
+    whole buffer onto every device. Instead each shard's block of messages
+    travels round a ring: the block bound for shard j starts on shard j+1
+    and moves one shard on per step, and every shard it visits adds the
+    messages of the senders it holds (zeros for the others); after a full
+    turn it is home. Each sum has one non-zero term, taken over the
+    payload's bits widened to int32, so the messages arrive bit for bit.
+    Rows and ring steps are loops, so a device holds one row's block and
+    its one contribution at a time."""
+    shards = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
+    ring = [(i, (i + 1) % shards) for i in range(shards)]
+
+    def take(buf, slots):
+        n, rest = buf.shape[1], buf.shape[2:]
+        m = n // shards
+        rows = slots.reshape(-1, slots.shape[-1])
+        w = rows.shape[1] // shards          # slot columns per shard
+
+        def local(buf_l, rows):
+            flat = buf_l.reshape((-1,) + rest)
+            me = lax.axis_index(axis)
+
+            def part(row, dst):
+                """This shard's messages for the columns of shard dst."""
+                cols = lax.dynamic_slice_in_dim(row, dst * w, w)
+                off = cols % n - me * m
+                mine = (off >= 0) & (off < m)
+                got = flat[jnp.where(mine, cols // n * m + off, 0)]
+                keep = mine.reshape(mine.shape + (1,) * len(rest))
+                return jnp.where(keep, _as_i32(got), 0)
+
+            def one_row(_, row):
+                # after step t this shard holds the block for shard me-t-1
+                def step(t, acc):
+                    acc = lax.ppermute(acc, axis, ring)
+                    return acc + part(row, (me - t - 1) % shards)
+                return None, lax.fori_loop(1, shards, step,
+                                           part(row, (me - 1) % shards))
+
+            _, out = lax.scan(one_row, None, rows)
+            return _from_i32(out, buf.dtype)
+
+        out = shard_map_compat(local, mesh=mesh, in_specs=(PS(None, axis),
+                                                           PS()),
+                               out_specs=PS(None, axis))(buf, rows)
+        return out.reshape(slots.shape + rest)
+
+    return take
+
+
+def _mesh_eval(mesh, axis: str):
+    """``simulation._eval`` for a node-sharded cache: every device takes the
+    eval nodes it holds (zeros elsewhere) and one all-reduce over the int32
+    bits assembles the subset on every device — exact, and without the
+    all-gather of the cache that slicing a sharded array would cost."""
+    def take(a, idx):
+        def local(a_l, idx):
+            m = a_l.shape[0]
+            off = idx - lax.axis_index(axis) * m
+            mine = (off >= 0) & (off < m)
+            got = cache_mod.take_nodes(a_l, jnp.clip(off, 0, m - 1))
+            keep = mine.reshape(mine.shape + (1,) * (a_l.ndim - 1))
+            return _from_i32(lax.psum(jnp.where(keep, _as_i32(got), 0),
+                                      axis), a.dtype)
+        return shard_map_compat(local, mesh=mesh, in_specs=(PS(axis), PS()),
+                                out_specs=PS())(a, idx)
+
+    def evaluate(cache, eval_idx, X_test, y_test):
+        sub = ModelCache(*(take(a, eval_idx) for a in cache))
+        return eval_subset(sub, X_test, y_test)
+
+    return evaluate
 
 
 # jitted chunk runners by config label, for compile-count accounting:
@@ -637,6 +742,9 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                          "(the Pallas receive kernel is dense)")
     D = delay_max
     codec = get_codec(wire)
+    sharded = mesh is not None and axis is not None
+    take = _mesh_take_slots(mesh, axis) if sharded else _take_slots
+    evaluate = _mesh_eval(mesh, axis) if sharded else _eval
     if use_send_kernel:
         from repro.kernels.gossip_cycle import quantize_send
 
@@ -653,11 +761,11 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             jnp apply paths; the Pallas path gets the raw packed codes plus
             their scale (and zero-point when the codec carries one) as
             kwargs — in-kernel decode."""
-            msg_w = buf_w.reshape(-1, buf_w.shape[-1])[idx]
+            msg_w = take(buf_w, idx)
             if not codec.quantized:
                 return msg_w, {}
-            msc = buf_scale.reshape(-1)[idx]
-            mzp = buf_zp.reshape(-1)[idx] if codec.has_zp else None
+            msc = take(buf_scale, idx)
+            mzp = take(buf_zp, idx) if codec.has_zp else None
             if use_pallas:
                 extra = dict(msg_scale=msc)
                 if codec.has_zp:
@@ -737,7 +845,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             n, d = last_w.shape
             Xc, yc = records(clock)
             msg_w, extra = gather(buf_w, buf_scale, buf_zp, idx, d)
-            msg_t = buf_t.reshape(-1)[idx]
+            msg_t = take(buf_t, idx)
             last_w, last_t, fresh_w, fresh_t, cache, gated, clipped = \
                 apply_fn(
                     last_w, last_t, fresh_w, fresh_t,
@@ -753,7 +861,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                     ef, clock + 1), (jnp.sum(gated), jnp.sum(clipped))
 
         def subset_apply(state, ridx, rslot, Xc, yc, buf_w, buf_scale,
-                         buf_zp, flat_t):
+                         buf_zp, buf_t):
             """Gather the ``ridx`` subset, run the (K', W)-round chain on
             it, scatter back — the shared core of both compact modes. Work
             tracks the packed width, not N; padding (-1) gathers node 0
@@ -770,7 +878,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             msg_w, _ = gather(buf_w, buf_scale, buf_zp, sc, d)
             lw2, lt2, fw2, ft2, sub2, g2, cl2 = apply_fn(
                 last_w[gi], last_t[gi], fresh_w[gi], fresh_t[gi], sub,
-                msg_w, flat_t[sc], vc, Xc[gi], yc[gi],
+                msg_w, take(buf_t, sc), vc, Xc[gi], yc[gi],
                 variant=variant, update=update)
             si = jnp.where(pad, n, gi)        # out of bounds => dropped
             last_w = last_w.at[si].set(lw2, mode="drop")
@@ -793,20 +901,19 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             (src0, ridx, rslot, *sm), kd = inp
             n, d = last_w.shape
             Xc, yc = records(clock)
-            flat_t = buf_t.reshape(-1)
             # round 1, dense over all nodes (same math as a K=1 dense apply)
             i0 = jnp.maximum(src0, 0)
             msg_w0, _ = gather(buf_w, buf_scale, buf_zp, i0[None], d)
             last_w, last_t, fresh_w, fresh_t, cache, g0, cl0 = apply_fn(
                 last_w, last_t, fresh_w, fresh_t,
                 ModelCache(cw, ct, ptr, cnt), msg_w0,
-                flat_t[i0][None], (src0 >= 0)[None], Xc, yc,
+                take(buf_t, i0[None]), (src0 >= 0)[None], Xc, yc,
                 variant=variant, update=update)
             # rounds >= 2: continue the chain on the multi-receiver subset
             # (their lastModel already holds the round-1 message)
             last_w, last_t, fresh_w, fresh_t, cache, g2, cl2 = subset_apply(
                 (last_w, last_t, fresh_w, fresh_t, cache), ridx, rslot,
-                Xc, yc, buf_w, buf_scale, buf_zp, flat_t)
+                Xc, yc, buf_w, buf_scale, buf_zp, buf_t)
             send_w, send_t = corrupt_send(fresh_w, fresh_t, cache, kd)
             buf_w, buf_scale, buf_zp, ef = send(
                 buf_w, buf_scale, buf_zp, ef, send_w, clock, kd,
@@ -850,7 +957,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                 send_w, send_t = faults_mod.corrupt_model(
                     fault, byz[gi], faults_mod.fault_key(
                         jax.random.wrap_key_data(kd)),
-                    send_w, send_t, old_w, old_t, rows=gi, n_total=n)
+                    send_w, send_t, old_w, old_t, rows=gi)
             sub_x = send_w + ef[gi] if codec.ef else send_w
             if not codec.quantized:
                 q = sub_x.astype(buf_w.dtype)
@@ -859,7 +966,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                 if codec.stochastic:
                     key = jax.random.split(
                         jax.random.wrap_key_data(kd), 4)[0]
-                    noise = sr_noise_for_rows(key, gi, d, n)
+                    noise = sr_noise_for_rows(key, gi, d)
                 q, sc, zp = codec.encode(sub_x, noise=noise)
                 if codec.ef:
                     resid = sub_x - codec.decode(q, sc, zp, d)
@@ -868,7 +975,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                 q = faults_mod.bitflip_payload(
                     byz[gi], faults_mod.fault_key(
                         jax.random.wrap_key_data(kd)),
-                    q, rows=gi, n_total=n)
+                    q, rows=gi)
             buf_w = buf_w.at[row, si].set(q, mode="drop")
             if codec.quantized:
                 buf_scale = buf_scale.at[row, si].set(sc, mode="drop")
@@ -882,7 +989,6 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
              buf_w, buf_t, buf_scale, buf_zp, ef, clock) = carry
             (ridx, rslot, sidx), kd = inp
             Xc, yc = records(clock)
-            flat_t = buf_t.reshape(-1)
             # every round over the round-1 receiver subset: non-receivers
             # are never touched, so per-cycle apply cost is
             # delivery-proportional (the sparse-delivery hot path) — and
@@ -891,7 +997,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             last_w, last_t, fresh_w, fresh_t, cache, g2, cl2 = subset_apply(
                 (last_w, last_t, fresh_w, fresh_t,
                  ModelCache(cw, ct, ptr, cnt)), ridx, rslot,
-                Xc, yc, buf_w, buf_scale, buf_zp, flat_t)
+                Xc, yc, buf_w, buf_scale, buf_zp, buf_t)
             buf_w, buf_t, buf_scale, buf_zp, ef = send_compact(
                 buf_w, buf_t, buf_scale, buf_zp, ef, fresh_w, fresh_t,
                 clock, kd, sidx, cache)
@@ -904,7 +1010,7 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
         carry, (g_cycles, cl_cycles) = lax.scan(body, carry,
                                                 (tables, keydata))
         cache = ModelCache(carry[4], carry[5], carry[6], carry[7])
-        errs = _eval(cache, eval_idx, X_test, y_test)
+        errs = evaluate(cache, eval_idx, X_test, y_test)
         if emit_streams:
             return carry, (errs, (g_cycles, cl_cycles))
         return carry, (errs, (jnp.sum(g_cycles), jnp.sum(cl_cycles)))
@@ -913,10 +1019,13 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
     # the index prefix keeps labels unique when configs differ only in a
     # field the label omits (lam, eta, mesh, ...); the fault/defense
     # suffixes appear only when active, so fault-free runs keep their
-    # pre-fault labels (and retrace budgets)
+    # pre-fault labels (and retrace budgets); "/interp" marks Pallas
+    # kernels run in interpret mode, so a chip run can assert it ran none
     label = (f"{len(_CHUNK_FNS)}:{variant}/{learner}/{mode}/{wire or 'f32'}"
              + ("/pallas" if use_pallas else "")
              + ("/sendk" if use_send_kernel else "")
+             + ("/interp" if interpret and (use_pallas or use_send_kernel)
+                else "")
              + (f"/fault:{fault_model}" if fault_model else "")
              + (f"/def:{defense}" if defense != "none" else "")
              + ("/telem" if emit_streams else ""))
@@ -935,7 +1044,7 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
                            sampler: str = "uniform", k_rounds: int = 4,
                            mesh=None, node_axis: Optional[str] = None,
                            use_pallas: Optional[bool] = None,
-                           interpret: Optional[bool] = None,
+                           interpret: bool = False,
                            compact_rounds: Optional[bool] = None,
                            compact_mode: Optional[str] = None,
                            use_send_kernel: Optional[bool] = None,
@@ -946,8 +1055,11 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     ``mesh``: optional ``jax.sharding.Mesh``; the node axis is split over
     ``node_axis`` (default: the mesh's first axis) — N must be divisible by
     that axis size. ``use_pallas`` selects the fused cycle kernel (default:
-    only on TPU; requires the Pegasos learner); ``interpret`` forces Pallas
-    interpret mode (default: on for non-TPU backends, for CPU testing).
+    on a TPU backend with the Pegasos learner, the only one the kernel
+    implements; asking for it with another learner is an error);
+    ``interpret`` runs the Pallas kernels in interpret mode, for CPU
+    tests. Neither is switched on or off behind the caller's back: Pallas
+    without ``interpret`` on a backend that is not a TPU fails to lower.
 
     ``compact_rounds`` allows the compacted receive paths (default: on
     whenever the vector apply runs, i.e. no Pallas — meshes included, via
@@ -1009,11 +1121,11 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
         eval_nodes=eval_nodes)
 
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if use_pallas and cfg.learner != "pegasos":
-        use_pallas = False            # kernel covers the P2Pegasos hot path
+        use_pallas = (jax.default_backend() == "tpu"
+                      and cfg.learner == "pegasos")
+    elif use_pallas and cfg.learner != "pegasos":
+        raise ValueError(f"the Pallas receive kernel implements the Pegasos "
+                         f"update only, not {cfg.learner!r}")
 
     node_sharding = None
     axis = None
@@ -1077,23 +1189,31 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     # paths carry nothing extra)
     sc_shape = (D, n) if codec.has_scale else (0, 0)
     zp_shape = (D, n) if codec.has_zp else (0, 0)
-    carry = (jnp.zeros((n, d), jnp.float32), jnp.zeros((n,), jnp.int32),
-             jnp.zeros((n, d), jnp.float32), jnp.zeros((n,), jnp.int32),
-             *cache_mod.init_cache(n, cfg.cache_size, d),
-             jnp.zeros((D, n, codec.payload_cols(d)), codec.payload_dtype),
-             jnp.zeros((D, n), jnp.int32),
-             jnp.zeros(sc_shape, jnp.float16),
-             jnp.zeros(zp_shape, jnp.float16),
-             jnp.zeros((n, d) if codec.ef else (0, 0), jnp.float32),
-             jnp.zeros((), jnp.int32))
-    if node_sharding is not None:
-        put_n = lambda a: (jax.device_put(a, node_sharding) if a.size
-                           else a)
-        put_dn = lambda a: (jax.device_put(
-            a, NamedSharding(mesh, PS(None, axis))) if a.size else a)
-        carry = tuple(put_n(a) for a in carry[:8]) + (
-            put_dn(carry[8]), put_dn(carry[9]), put_dn(carry[10]),
-            put_dn(carry[11]), put_n(carry[12]), carry[13])
+
+    def init_carry():
+        return (jnp.zeros((n, d), jnp.float32), jnp.zeros((n,), jnp.int32),
+                jnp.zeros((n, d), jnp.float32), jnp.zeros((n,), jnp.int32),
+                *cache_mod.init_cache(n, cfg.cache_size, d),
+                jnp.zeros((D, n, codec.payload_cols(d)),
+                          codec.payload_dtype),
+                jnp.zeros((D, n), jnp.int32),
+                jnp.zeros(sc_shape, jnp.float16),
+                jnp.zeros(zp_shape, jnp.float16),
+                jnp.zeros((n, d) if codec.ef else (0, 0), jnp.float32),
+                jnp.zeros((), jnp.int32))
+
+    if node_sharding is None:
+        carry = init_carry()
+    else:
+        # every lane is made on its shards: made whole on one device and
+        # then split, the state of a population that needs the mesh would
+        # not fit that device
+        put_n = lambda a: jax.device_put(a, node_sharding) if a.size else a
+        dn, rep = NamedSharding(mesh, PS(None, axis)), NamedSharding(mesh, PS())
+        lanes = (node_sharding,) * 8 + (dn,) * 4 + (node_sharding, rep)
+        carry = jax.jit(init_carry, out_shardings=tuple(
+            s if a.size else rep
+            for a, s in zip(jax.eval_shape(init_carry), lanes)))()
         X, y = put_n(X), put_n(y)
         if byz is not None:
             byz = put_n(byz)
@@ -1312,6 +1432,7 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
         packed_widths=dict(widths), shards=shards)
     res.wire_bytes_total = res.sent_total * message_wire_bytes(d, cfg.wire_dtype)
     res.ef_residual_norm = ef_residual_norm(carry[12])
+    res.in_flight = int(router.p_slot.size)
     if armed:
         tel.annotations.setdefault("runs", []).append(dict(
             engine="sharded", n_nodes=n, cycles=cycles,

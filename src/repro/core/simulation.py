@@ -450,6 +450,11 @@ class SimResult:
     # rejected by the receive-side defense ("gated"), and rescaled by
     # norm_clip ("clipped") — all zero on fault-free / defense-off runs
     fault_stats: Dict[str, int] = field(default_factory=dict)
+    # messages sent but neither delivered, lost nor overflowed by the end
+    # of the run — sharded engine only: the host router's pending set, an
+    # independent count, so ``sent_total == delivered_total + lost_total
+    # + overflow_total + in_flight`` checks the message economy
+    in_flight: int = 0
 
 
 def ef_residual_norm(ef) -> float:
@@ -519,8 +524,13 @@ def eval_points(cycles: int, eval_every: int) -> List[int]:
 
 @functools.partial(jax.jit, static_argnames=())
 def _eval(cache: ModelCache, eval_idx, X_test, y_test):
-    sub = ModelCache(cache.w[eval_idx], cache.t[eval_idx],
-                     cache.ptr[eval_idx], cache.count[eval_idx])
+    sub = ModelCache(*(cache_mod.take_nodes(a, eval_idx) for a in cache))
+    return eval_subset(sub, X_test, y_test)
+
+
+def eval_subset(sub: ModelCache, X_test, y_test):
+    """Population error over the gathered eval-node caches: (PREDICT
+    error, VOTEDPREDICT error, mean pairwise cosine of the models)."""
     fresh = cache_mod.predict_fresh(sub, X_test)         # (E, m)
     voted = cache_mod.voted_predict(sub, X_test)
     err_f = jnp.mean(fresh != y_test[None, :], axis=1).mean()

@@ -105,56 +105,38 @@ def threefry2x32(k0, k1, x0, x1):
     return x[0], x[1]
 
 
-def uniform_at(k0, k1, p, size: int):
+def uniform_at(k0, k1, p):
     """``jax.random.uniform(key, shape)`` evaluated at flat positions ``p``
-    of an array with ``size`` total elements.
+    (non-negative int32, so < 2^31) of the drawn array.
 
-    Reproduces the original (non-partitionable) threefry counter scheme of
-    ``jax._src.prng._threefry_random_bits_original`` bit for bit: the iota
-    counter array of ``size`` elements is split in half (odd sizes pad one
-    zero), element p < half is lane 0 of the block (p, half+p), element
-    p >= half is lane 1 of the block (p-half, p) — each element evaluates
-    exactly one 20-round block, with no cross-lane communication. The
-    uint32 bits map to [0, 1) floats with the same mantissa-fill transform
-    ``jax.random.uniform`` applies.
+    Reproduces JAX's partitionable threefry counter scheme
+    (``jax._src.prng._threefry_random_bits_partitionable``, the default of
+    the installed JAX) bit for bit: element p is one 20-round block on the
+    64-bit counter (hi32(p), lo32(p)) = (0, p), and its 32 random bits are
+    ``y0 ^ y1``. Each element depends on its own position only, not on the
+    array size. The uint32 bits map to [0, 1) floats with the same
+    mantissa-fill transform ``jax.random.uniform`` applies.
 
     This is what lets both the Pallas send kernel and the compacted
     send path regenerate the "int8_sr" noise for an arbitrary *subset* of
     messages without a dense (N, d) draw, bitwise-equal to the full-array
     ``jax.random.uniform`` the reference engine consumes."""
-    if jax.config.jax_threefry_partitionable:
-        # the partitionable PRNG uses a different counter scheme: this
-        # helper would silently diverge from jax.random.uniform and break
-        # the engines' bitwise int8_sr parity contract — fail loudly
-        # instead (supporting it means implementing the partitionable
-        # scheme here AND in the Pallas send kernel, both parity-tested)
-        raise NotImplementedError(
-            "uniform_at implements the original (non-partitionable) "
-            "threefry counter scheme; run with "
-            "jax_threefry_partitionable=False for the int8_sr wire dtype")
-    half = (size + 1) // 2
-    is_lo = p < half
-    pair = p + half
-    x0 = jnp.where(is_lo, p, p - half)
-    # the odd-size zero pad sits at padded position `size`
-    x1 = jnp.where(is_lo, jnp.where(pair < size, pair, 0), p)
-    y0, y1 = threefry2x32(k0, k1, x0.astype(jnp.uint32),
-                          x1.astype(jnp.uint32))
-    bits = jnp.where(is_lo, y0, y1)
-    fbits = (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
+    lo = p.astype(jnp.uint32)
+    y0, y1 = threefry2x32(k0, k1, jnp.zeros_like(lo), lo)
+    fbits = ((y0 ^ y1) >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
     return jax.lax.bitcast_convert_type(fbits, jnp.float32) - 1.0
 
 
-def sr_noise_for_rows(key, rows, d: int, n_total: int):
-    """The ``jax.random.uniform(key, (n_total, d))`` noise of a full-array
+def sr_noise_for_rows(key, rows, d: int):
+    """The ``jax.random.uniform(key, (n, d))`` noise of a full-array
     "int8_sr" quantization, evaluated only at the given ``rows``:
-    ``sr_noise_for_rows(key, rows, d, n)`` ==
-    ``jax.random.uniform(key, (n, d))[rows]`` bitwise, at O(len(rows)·d)
-    threefry work. ``key`` is a typed threefry key (the per-cycle
-    ``k_recv`` slot)."""
+    ``sr_noise_for_rows(key, rows, d)`` ==
+    ``jax.random.uniform(key, (n, d))[rows]`` bitwise for any n, at
+    O(len(rows)·d) threefry work. ``key`` is a typed threefry key (the
+    per-cycle ``k_recv`` slot)."""
     kd = jax.random.key_data(key).astype(jnp.uint32)
     p = rows[:, None] * d + jnp.arange(d, dtype=rows.dtype)[None, :]
-    return uniform_at(kd[0], kd[1], p, n_total * d)
+    return uniform_at(kd[0], kd[1], p)
 
 
 # ---------------------------------------------------------------------------

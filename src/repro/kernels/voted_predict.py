@@ -10,11 +10,13 @@ bitwise (the vote counts are exact small-integer sums, and the tie-break
 ``p_ratio - 0.5 >= 0`` and the ``score >= 0`` sign convention are applied
 identically — pinned by tests/test_serving.py).
 
-TPU adaptation: cache rows are tiled (BLK_M, c_pad, d_pad) with d padded
-to the 128-lane boundary and the cache axis to the f32 sublane multiple;
-pad lanes are masked out of the score reduction and pad cache slots out
-of the vote (the ``fused_receive_apply`` masking precedent) — a padded
-query row carries count 0 and is sliced off by the caller.
+TPU adaptation: node-minor, like ``kernels/gossip_cycle.py``. Queries sit
+on the 128-wide lane axis: the gathered cache rows are read as (C, d, M),
+the query points as (d, M) and the counts as (1, M) — XLA's own TPU
+layouts for the (M, C, d) / (M, d) gathers, so the transposes are free.
+Every other block dimension is the full array dimension, so nothing is
+padded; a ragged last block computes garbage lanes that are never written
+back.
 """
 from __future__ import annotations
 
@@ -25,35 +27,26 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.pegasos_update import _pad_to
-
-BLK_M = 8          # queries per grid step
-LANE = 128         # TPU lane width: d padded to a multiple
-C_SUB = 8          # f32 sublane multiple: cache axis padded to it
+from repro.kernels.gossip_cycle import _lanes, _node_block
 
 
-def _voted_kernel(w_ref, x_ref, cnt_ref, out_ref, *, c_real: int,
-                  d_real: int):
-    w = w_ref[...].astype(jnp.float32)          # (BLK_M, c_pad, d_pad)
-    x = x_ref[...].astype(jnp.float32)          # (BLK_M, d_pad)
-    cnt = cnt_ref[...]                          # (BLK_M,) int32
-    blk, c_pad, d_pad = w.shape
+def _voted_kernel(w_ref, x_ref, cnt_ref, out_ref):
+    w = w_ref[...].astype(jnp.float32)          # (C, d, BLK)
+    x = x_ref[...].astype(jnp.float32)          # (d, BLK)
+    cnt = cnt_ref[...]                          # (1, BLK) int32
+    c, _, blk = w.shape
 
-    # score each (query, cache slot) pair; pad d-lanes masked to zero keeps
-    # the reduction bitwise-clean like the fused_receive_apply margins
-    lane = lax.broadcasted_iota(jnp.int32, (blk, c_pad, d_pad), 2)
-    prod = jnp.where(lane < d_real, w * x[:, None, :], 0.0)
-    scores = jnp.sum(prod, axis=-1)             # (BLK_M, c_pad)
+    # score each (cache slot, query) pair over the coefficient axis
+    scores = jnp.sum(w * x[None], axis=1)       # (C, BLK)
 
     # Algorithm 4 vote: score >= 0 counts positive (the score == 0 sign
     # convention of cache.voted_predict); only the first `count` ring
-    # slots are valid — which also masks every padded cache slot, since
-    # count <= c_real <= c_pad
+    # slots are valid
     votes = (scores >= 0).astype(jnp.float32)
-    slot = lax.broadcasted_iota(jnp.int32, (blk, c_pad), 1)
-    pos = jnp.sum(jnp.where(slot < cnt[:, None], votes, 0.0), axis=-1)
-    # pad query rows ride with count 0: max(cnt, 1) only guards their
-    # division — real rows always have count >= 1 (init_cache seeds one)
+    slot = lax.broadcasted_iota(jnp.int32, (c, blk), 0)
+    pos = jnp.sum(jnp.where(slot < cnt, votes, 0.0), axis=0, keepdims=True)
+    # real queries always have count >= 1 (init_cache seeds one);
+    # max(cnt, 1) only guards the garbage lanes of a ragged last block
     p_ratio = pos / jnp.maximum(cnt, 1).astype(jnp.float32)
     out_ref[...] = jnp.where(p_ratio - 0.5 >= 0, 1.0, -1.0)
 
@@ -64,22 +57,13 @@ def voted_predict_batched(w, count, X, *, interpret: bool = False):
     valid-slot counts; X: (M, d) query points. Returns (M,) ±1 f32
     predictions — the majority vote of each query's assigned cache."""
     m, c, d = w.shape
-    wp = _pad_to(_pad_to(_pad_to(w, LANE, 2), C_SUB, 1), BLK_M, 0)
-    xp = _pad_to(_pad_to(X, LANE, 1), BLK_M, 0)
-    cntp = _pad_to(count.astype(jnp.int32), BLK_M, 0)
-    mp, c_pad, d_pad = wp.shape
-    grid = (mp // BLK_M,)
-
+    blk = _node_block(m)
     out = pl.pallas_call(
-        functools.partial(_voted_kernel, c_real=c, d_real=d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BLK_M, c_pad, d_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BLK_M, d_pad), lambda i: (i, 0)),
-            pl.BlockSpec((BLK_M,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((BLK_M,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.float32),
+        _voted_kernel,
+        grid=(pl.cdiv(m, blk),),
+        in_specs=[_lanes(blk, c, d), _lanes(blk, d), _lanes(blk, 1)],
+        out_specs=_lanes(blk, 1),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
         interpret=interpret,
-    )(wp, xp, cntp)
-    return out[:m]
+    )(w.transpose(1, 2, 0), X.T, count.astype(jnp.int32).reshape(1, m))
+    return out[0]

@@ -72,7 +72,8 @@ class GossipServer:
     ``policy``: node assignment for incoming queries
     (``serving.ASSIGN_POLICIES``). ``use_kernel`` answers with the fused
     Pallas ``voted_predict_batched`` path instead of the jnp einsum path —
-    the two are bitwise-interchangeable. ``compare_fresh`` additionally
+    the two are bitwise-interchangeable; ``interpret`` runs that kernel in
+    Pallas interpret mode (CPU tests), never implicitly. ``compare_fresh`` additionally
     answers every batch with the freshest-model PREDICT (outside the
     latency window) for the fresh-vs-voted accuracy comparison. For a
     fixed ``seed`` and submission order the served answers are
@@ -81,6 +82,7 @@ class GossipServer:
     policy: str = "uniform"
     seed: int = 0
     use_kernel: bool = False
+    interpret: bool = False
     compare_fresh: bool = True
     telemetry: Optional[telemetry_mod.Telemetry] = None
 
@@ -155,7 +157,8 @@ class GossipServer:
 
         t0 = time.perf_counter()
         if self.use_kernel:
-            preds = serving.serve_voted_kernel(snap.w, snap.count, xj, aj)
+            preds = serving.serve_voted_kernel(snap.w, snap.count, xj, aj,
+                                               interpret=self.interpret)
         else:
             preds = serving.serve_voted(snap.w, snap.count, xj, aj)
         preds.block_until_ready()

@@ -1,9 +1,8 @@
-"""Version-compat wrapper for ``shard_map``.
+"""One wrapper for ``jax.shard_map``.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (kwargs
-``check_rep``/``auto``) to top-level ``jax.shard_map`` (kwargs
-``check_vma``/``axis_names``) across JAX releases. Every call site in this
-repo goes through :func:`shard_map_compat` so both spellings work.
+Every call site in this repo goes through :func:`shard_map_compat`, so the
+keyword spelling (``check_vma``/``axis_names``) lives in one place and the
+``shardmap-spec-arity`` lint rule has one call to look for.
 """
 from __future__ import annotations
 
@@ -12,23 +11,15 @@ import jax
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes=None,
                      check: bool = False):
-    """``shard_map(f, mesh, in_specs, out_specs)`` on any supported JAX.
+    """``jax.shard_map(f, mesh, in_specs, out_specs)``.
 
     ``manual_axes``: mesh axes the body handles manually; the remaining
-    axes stay auto-sharded (partial-manual mode — ``axis_names`` on newer
-    JAX, ``auto`` = the complement on older JAX). ``None`` means fully
-    manual over every mesh axis.
+    axes stay auto-sharded (partial-manual mode, ``axis_names``). ``None``
+    means fully manual over every mesh axis.
 
-    ``check=False`` disables replication/varying-manual-axes checking
-    (``check_rep`` on older JAX, ``check_vma`` on newer) — the call sites
-    here permute or act element-wise per shard, which the checker cannot
-    always verify."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if manual_axes is None else {"axis_names": set(manual_axes)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check, **kw)
-    from jax.experimental.shard_map import shard_map
-    auto = (frozenset() if manual_axes is None
-            else frozenset(mesh.axis_names) - frozenset(manual_axes))
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check, auto=auto)
+    ``check=False`` disables varying-manual-axes checking (``check_vma``)
+    — the call sites here permute or act element-wise per shard, which the
+    checker cannot always verify."""
+    kw = {} if manual_axes is None else {"axis_names": set(manual_axes)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kw)

@@ -12,15 +12,8 @@ import jax.numpy as jnp
 
 
 def tree_leaves_with_path(tree):
-    """Compat shim for ``jax.tree.leaves_with_path``.
-
-    The ``jax.tree`` alias namespace gained ``leaves_with_path`` only in
-    newer JAX releases; ``jax.tree_util.tree_leaves_with_path`` is the
-    stable spelling that exists on every version this repo supports."""
-    fn = getattr(jax.tree, "leaves_with_path", None)
-    if fn is not None:
-        return fn(tree)
-    return jax.tree_util.tree_leaves_with_path(tree)
+    """``jax.tree.leaves_with_path``: (key path, leaf) pairs."""
+    return jax.tree.leaves_with_path(tree)
 
 
 def tree_add(a, b):

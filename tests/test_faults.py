@@ -186,7 +186,7 @@ def test_corrupt_model_subset_matches_dense_gather():
     dense, _ = corrupt_model(fault, byz, key, w, t)
     rows = jnp.asarray([3, 0, 31, 17, 8])
     sub, _ = corrupt_model(fault, byz[rows], key, w[rows], t[rows],
-                           rows=rows, n_total=n)
+                           rows=rows)
     np.testing.assert_array_equal(np.asarray(dense)[np.asarray(rows)],
                                   np.asarray(sub))
 
@@ -220,8 +220,7 @@ def test_bitflip_subset_matches_dense_gather():
     key = fault_key(jax.random.key(11))
     dense = bitflip_payload(byz, key, payload)
     rows = jnp.asarray([23, 1, 12, 0, 7, 19])
-    sub = bitflip_payload(byz[rows], key, payload[rows], rows=rows,
-                          n_total=n)
+    sub = bitflip_payload(byz[rows], key, payload[rows], rows=rows)
     np.testing.assert_array_equal(np.asarray(dense)[np.asarray(rows)],
                                   np.asarray(sub))
 
@@ -303,20 +302,24 @@ def test_cosine_gate_rejects_anti_aligned_only():
 
 
 def test_defense_real_mask_ignores_pad_lanes():
-    """The Pallas padded-width contract: garbage beyond d_real must not
-    change any defense decision or rescale."""
-    msg = jnp.asarray([[3.0, 4.0, 1e30, jnp.nan]], jnp.float32)
-    recv = jnp.asarray([[1.0, 0.0, 1e30, 7.0]], jnp.float32)
-    real = jnp.asarray([[True, True, False, False]])
-    valid = jnp.ones(1, bool)
-    got = apply_defense("norm_clip", msg, valid, recv, real=real)
-    exp = apply_defense("norm_clip", msg[:, :2], valid, recv[:, :2])
-    assert bool(got[1][0]) == bool(exp[1][0])
-    assert bool(got[3][0]) == bool(exp[3][0])
-    _, vm_g, _, _ = apply_defense("cosine_gate", msg, valid, recv, real=real)
-    _, vm_e, _, _ = apply_defense("cosine_gate", msg[:, :2], valid,
-                                  recv[:, :2])
-    assert bool(vm_g[0]) == bool(vm_e[0])
+    """The Pallas kernel's node-minor layout: payloads as (d, m) columns
+    screened with ``axis=0`` give the same decisions and rescales as the
+    (m, d) rows, and a garbage column (the ragged last node block) does
+    not change any other node's result."""
+    msg = jnp.asarray([[3.0, 4.0], [0.5, -0.2], [1e30, jnp.nan]],
+                      jnp.float32)
+    recv = jnp.asarray([[1.0, 0.0], [-0.5, 0.2], [1e30, 7.0]], jnp.float32)
+    valid = jnp.ones(3, bool)
+    for defense in ("norm_clip", "cosine_gate"):
+        rows = apply_defense(defense, msg, valid, recv)
+        cols = apply_defense(defense, msg.T, valid[None, :], recv.T, axis=0)
+        for r, c in zip(rows[1:], cols[1:]):
+            np.testing.assert_array_equal(np.asarray(r), np.asarray(c)[0])
+        np.testing.assert_array_equal(np.asarray(rows[0])[:2],
+                                      np.asarray(cols[0]).T[:2])
+        clean = apply_defense(defense, msg[:2], valid[:2], recv[:2])
+        for r, c in zip(rows, clean):
+            np.testing.assert_array_equal(np.asarray(r)[:2], np.asarray(c))
 
 
 # ---------------------------------------------------------------------------

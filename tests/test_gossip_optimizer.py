@@ -83,7 +83,7 @@ def test_perms_for_step_pod_schedule():
 def test_linear_gossip_mesh_step_shard_map():
     """The paper's protocol with peers = mesh devices via shard_map."""
     from jax.sharding import Mesh, PartitionSpec as PS
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     import functools
 
     mesh = jax.make_mesh((1,), ("data",))

@@ -51,7 +51,7 @@ def test_merge_update_kernel_sweep(n, d):
                                      (8, 5, 128, 1)])
 def test_gossip_cycle_kernel_sweep(variant, n, c, d, k):
     """Fused deliver→merge→update→cache-write vs the apply_receives oracle."""
-    from repro.core.cache import ModelCache
+    from repro.core.cache import ModelCache, freshest
     from repro.core.learners import make_update
     from repro.core.simulation import apply_receives
 
@@ -74,11 +74,17 @@ def test_gossip_cycle_kernel_sweep(variant, n, c, d, k):
     exp_lw, exp_lt, exp_cache, _, _ = apply_receives(
         last_w, last_t, cache, msg_w, msg_t, valid, x, y,
         variant=variant, update=upd)
+    fresh_w, fresh_t = freshest(cache)
     got = gc.fused_receive_apply(
-        last_w, last_t, cache.w, cache.t, cache.ptr, cache.count,
-        msg_w, msg_t, valid.astype(jnp.int32), x, y,
+        last_w, last_t, fresh_w, fresh_t, cache.w, cache.t, cache.ptr,
+        cache.count, msg_w, msg_t, valid.astype(jnp.int32), x, y,
         variant=variant, lam=lam, interpret=True)
-    got_lw, got_lt, got_cw, got_ct, got_ptr, got_cnt, _, _ = got
+    (got_lw, got_lt, got_fw, got_ft, got_cw, got_ct, got_ptr, got_cnt,
+     _, _) = got
+    exp_fw, exp_ft = freshest(exp_cache)
+    np.testing.assert_allclose(np.asarray(got_fw), np.asarray(exp_fw),
+                               rtol=2e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got_ft), np.asarray(exp_ft))
     np.testing.assert_allclose(np.asarray(got_lw), np.asarray(exp_lw),
                                rtol=2e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(got_lt), np.asarray(exp_lt))

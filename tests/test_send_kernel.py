@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core.gossip_optimizer import quantize_wire
-from repro.kernels.gossip_cycle import _uniform_at, quantize_send
+from repro.core.wire_codec import uniform_at
+from repro.kernels.gossip_cycle import quantize_send
 
 
 def rand_w(n, d, seed=0, spread=True):
@@ -33,11 +34,12 @@ def test_quantize_send_matches_quantize_wire_int8(n, d):
     np.testing.assert_array_equal(np.asarray(z0), np.asarray(z1))
 
 
-@pytest.mark.parametrize("n,d", [(64, 10), (33, 7), (5, 3), (96, 57)])
+@pytest.mark.parametrize("n,d", [(64, 10), (33, 7), (5, 3), (96, 57),
+                                 (1100, 57)])
 def test_quantize_send_matches_quantize_wire_int8_sr(n, d):
     """Stochastic rounding: the in-kernel threefry draw must equal the
-    ``jax.random.uniform(k_recv, (n, d))`` draw of the jnp path — both even
-    and odd counter sizes (the odd case exercises the zero pad)."""
+    ``jax.random.uniform(k_recv, (n, d))`` draw of the jnp path, for even
+    and odd sizes and a population that spans several node blocks."""
     w = rand_w(n, d, seed=n + 1)
     key = jax.random.split(jax.random.key(42), 4)[0]     # a k_recv slot
     q0, s0, z0 = quantize_wire(w, "int8_sr", key=key)
@@ -51,12 +53,33 @@ def test_quantize_send_matches_quantize_wire_int8_sr(n, d):
 
 @pytest.mark.parametrize("size", [8, 9, 2048, 2049])
 def test_uniform_at_matches_jax_random_uniform(size):
-    """The kernel's threefry helper IS jax.random.uniform, elementwise."""
+    """The kernel's threefry helper IS jax.random.uniform, elementwise,
+    under the partitionable counter scheme of the installed JAX."""
+    assert jax.config.jax_threefry_partitionable
     key = jax.random.key(123)
     ref = jax.random.uniform(key, (size,))
     k0, k1 = (jnp.uint32(x) for x in np.asarray(jax.random.key_data(key)))
-    got = _uniform_at(k0, k1, jnp.arange(size, dtype=jnp.int32), size)
+    got = uniform_at(k0, k1, jnp.arange(size, dtype=jnp.int32))
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
+
+def test_in_kernel_f16_rounding_matches_cast():
+    """The send kernels round scales to f16 with integer ops (no f16
+    conversion in Mosaic on v5e): equal to the saturating f16 cast on
+    normals, subnormals, exact ties and out-of-range values."""
+    from repro.core.wire_codec import _sat_f16
+    from repro.kernels.gossip_cycle import _sat_f16 as kernel_sat_f16
+
+    rng = np.random.default_rng(4)
+    ties = (np.arange(1, 2049, dtype=np.float32) + 0.5) * 2.0 ** -24
+    v = np.concatenate([
+        rng.normal(size=4096) * 10.0 ** rng.uniform(-9, 5, size=4096),
+        ties, -ties, 1.0 + np.arange(64) * 2.0 ** -11 + 2.0 ** -12,
+        [0.0, -0.0, 65504.0, 65519.0, 65520.0, 1e9, -1e9, 2.0 ** -14,
+         2.0 ** -25, 3 * 2.0 ** -26]]).astype(np.float32)
+    want = np.asarray(_sat_f16(jnp.asarray(v)).astype(jnp.float32))
+    got = np.asarray(jax.jit(kernel_sat_f16)(jnp.asarray(v)))
+    np.testing.assert_array_equal(want.view(np.uint32), got.view(np.uint32))
 
 
 def test_constant_and_degenerate_rows():

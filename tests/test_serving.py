@@ -81,7 +81,8 @@ def test_kernel_matches_jnp_path(n, c, d, m, adds):
     exp = serving.serve_voted(cache.w, cache.count, X, assign)
     direct = voted_predict_batched(cache.w[assign], cache.count[assign], X,
                                    interpret=True)
-    wrapped = serving.serve_voted_kernel(cache.w, cache.count, X, assign)
+    wrapped = serving.serve_voted_kernel(cache.w, cache.count, X, assign,
+                                         interpret=True)
     npt.assert_array_equal(np.asarray(direct), np.asarray(exp))
     npt.assert_array_equal(np.asarray(wrapped), np.asarray(exp))
 
@@ -125,7 +126,8 @@ def test_voted_predict_tie_breaks_positive():
     got = serving.serve_voted(cache.w, cache.count, X_E0,
                               jnp.zeros(1, jnp.int32))
     gotk = serving.serve_voted_kernel(cache.w, cache.count, X_E0,
-                                      jnp.zeros(1, jnp.int32))
+                                      jnp.zeros(1, jnp.int32),
+                                      interpret=True)
     npt.assert_array_equal(np.asarray(got), [1.0])
     npt.assert_array_equal(np.asarray(gotk), [1.0])
 
@@ -151,7 +153,8 @@ def test_voted_predict_zero_score_votes_positive():
         np.asarray(cache_mod.voted_predict(init, X_E0)), np.ones((3, 1)))
     npt.assert_array_equal(
         np.asarray(serving.serve_voted_kernel(
-            init.w, init.count, X_E0, jnp.zeros(1, jnp.int32))), [1.0])
+            init.w, init.count, X_E0, jnp.zeros(1, jnp.int32),
+            interpret=True)), [1.0])
 
 
 def test_voted_predict_diverges_from_score_sum_vote():
@@ -246,7 +249,7 @@ def test_served_answers_reproducible_and_kernel_invariant(use_kernel):
     both serve paths — and the kernel path answers == the jnp path."""
     def serve_once(kernel):
         srv = GossipServer(batch_size=16, policy="uniform", seed=5,
-                           use_kernel=kernel)
+                           use_kernel=kernel, interpret=kernel)
         qX = np.asarray(_sim_setup()[3][:40], np.float32)
 
         def hook(cycle, snap):

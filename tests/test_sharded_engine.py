@@ -89,6 +89,17 @@ def test_sharded_pallas_kernel_matches_reference(variant, wire):
     assert_curves_close(ref, sh)
 
 
+def test_pallas_kernel_refuses_other_learners():
+    """The receive kernel implements the Pegasos update only: asking for it
+    with another learner is an error, not a quiet switch to the vector
+    apply."""
+    X, y, Xt, yt = toy(n=32)
+    cfg = small_cfg(n_nodes=32, learner="adaline")
+    with pytest.raises(ValueError, match="Pegasos"):
+        run_simulation(cfg, X, y, Xt, yt, cycles=2, engine="sharded",
+                       use_pallas=True, interpret=True)
+
+
 @pytest.mark.parametrize("sampler", ["uniform", "matching"])
 @pytest.mark.parametrize("n", [32, 33])
 def test_sharded_engine_odd_and_even_populations(sampler, n):

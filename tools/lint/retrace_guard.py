@@ -164,7 +164,7 @@ def _mini_suite():
     # fixed-shape, so each serve path must compile exactly once
     from repro.launch.gossip_serve import GossipServer
     srv = GossipServer(batch_size=16, use_kernel=False)
-    srv_k = GossipServer(batch_size=16, use_kernel=True)
+    srv_k = GossipServer(batch_size=16, use_kernel=True, interpret=True)
     Xq = Xt[:16]
 
     def serve_hook(cycle, snap):

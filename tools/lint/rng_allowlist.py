@@ -41,7 +41,9 @@ RNG_ALLOWED: Dict[Tuple[str, str], FrozenSet[str]] = {
     ("core/peer_sampling.py", "uniform_peers"): frozenset({"randint"}),
     ("core/peer_sampling.py", "perfect_matching"): frozenset({"permutation"}),
     # int8_sr wire noise from k_recv (the slot the float codecs leave
-    # unused), uniform over the full (N, d) block
+    # unused), uniform over the full (N, d) block; under the partitionable
+    # counter scheme element p is threefry(k_recv, (0, p)), which is how
+    # wire_codec.uniform_at (no jax.random call) regenerates any subset
     ("core/wire_codec.py", "quantize_wire"): frozenset({"uniform"}),
     # adversarial fault stream: k_fault = fold_in(cycle key, FAULT_FOLD)
     # DERIVES a side key without consuming from the pinned 4-way split, so
