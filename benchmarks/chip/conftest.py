@@ -1,0 +1,9 @@
+import sys
+from pathlib import Path
+
+# the tests import the benchmark as ``benchmarks.chip`` and the program
+# from ``src``, whichever directory pytest was started from
+_ROOT = Path(__file__).resolve().parents[2]
+for p in (str(_ROOT / "src"), str(_ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
