@@ -131,7 +131,7 @@ def run(quick: bool = False) -> dict:
         cfg = _cfg(n, d, scenario, wire_dtype=wire)
         # warm-up run compiles (same chunk length as the timed run); the
         # timed runs measure steady state via the shared min-time estimator
-        # (telemetry.best_of) — the shared 2-core container's noise is
+        # (common.best_of) — the shared 2-core container's noise is
         # strictly additive. eval_every=10 gives paper-style curves and
         # lets the sharded engine pipeline host routing against the
         # in-flight device scan.

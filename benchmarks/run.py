@@ -10,8 +10,7 @@ import time
 
 SUITES = ["table1", "fig1", "fig2", "fig3", "theory", "kernels",
           "gossip_vs_allreduce", "roofline", "population_scaling",
-          "wire_quantization", "robustness", "serving",
-          "telemetry_overhead"]
+          "wire_quantization", "robustness", "serving"]
 
 
 def main() -> None:
@@ -63,9 +62,6 @@ def main() -> None:
     if "serving" in only:
         from benchmarks import serving
         serving.run(args.quick)
-    if "telemetry_overhead" in only:
-        from benchmarks import telemetry_overhead
-        telemetry_overhead.run(args.quick)
     print(f"benchmarks done in {time.time()-t0:.1f}s")
 
 

@@ -91,7 +91,7 @@ from repro.core import telemetry as telemetry_mod
 from repro.core.simulation import (SimResult, _eval, ef_residual_norm,
                                    eval_points, eval_subset,
                                    message_wire_bytes, payload_buffer_bytes,
-                                   sim_setup)
+                                   setup_h2d_bytes, sim_setup)
 from repro.core.wire_codec import get_codec, sr_noise_for_rows
 from repro.sharding.compat import shard_map_compat
 
@@ -612,7 +612,8 @@ def _mesh_take_slots(mesh, axis: str):
                 return None, lax.fori_loop(1, shards, step,
                                            part(row, (me - 1) % shards))
 
-            _, out = lax.scan(one_row, None, rows)
+            with jax.named_scope("ring"):
+                _, out = lax.scan(one_row, None, rows)
             return _from_i32(out, buf.dtype)
 
         out = shard_map_compat(local, mesh=mesh, in_specs=(PS(None, axis),
@@ -730,7 +731,12 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
     instead of their jitted sums — the driver sums on the host (exact for
     integers) and emits them as per-cycle streams. Unarmed runs keep the
     pre-telemetry program byte for byte; armed fns get a distinct
-    "/telem" label (and their own retrace budget)."""
+    "/telem" label (and their own retrace budget).
+
+    The scan body's stages run under ``jax.named_scope``s — ``gather``,
+    ``receive``, ``send``, ``eval``, and ``ring`` in the mesh's message
+    gather — which name them in a device profile's op metadata and change
+    nothing else in the compiled program."""
     update = make_update(learner, lam=lam, eta=eta)
     fault = faults_mod.get_fault(fault_model)
     apply_fn = (_pallas_apply(lam, interpret, wire, defense) if use_pallas
@@ -844,18 +850,21 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             idx = jnp.maximum(src_slot, 0)
             n, d = last_w.shape
             Xc, yc = records(clock)
-            msg_w, extra = gather(buf_w, buf_scale, buf_zp, idx, d)
-            msg_t = take(buf_t, idx)
-            last_w, last_t, fresh_w, fresh_t, cache, gated, clipped = \
-                apply_fn(
-                    last_w, last_t, fresh_w, fresh_t,
-                    ModelCache(cw, ct, ptr, cnt), msg_w, msg_t, valid,
-                    Xc, yc, variant=variant, update=update, **extra)
-            send_w, send_t = corrupt_send(fresh_w, fresh_t, cache, kd)
-            buf_w, buf_scale, buf_zp, ef = send(
-                buf_w, buf_scale, buf_zp, ef, send_w, clock, kd,
-                sm[0] if sm else None)
-            buf_t = buf_t.at[clock % D].set(send_t)
+            with jax.named_scope("gather"):
+                msg_w, extra = gather(buf_w, buf_scale, buf_zp, idx, d)
+                msg_t = take(buf_t, idx)
+            with jax.named_scope("receive"):
+                last_w, last_t, fresh_w, fresh_t, cache, gated, clipped = \
+                    apply_fn(
+                        last_w, last_t, fresh_w, fresh_t,
+                        ModelCache(cw, ct, ptr, cnt), msg_w, msg_t, valid,
+                        Xc, yc, variant=variant, update=update, **extra)
+            with jax.named_scope("send"):
+                send_w, send_t = corrupt_send(fresh_w, fresh_t, cache, kd)
+                buf_w, buf_scale, buf_zp, ef = send(
+                    buf_w, buf_scale, buf_zp, ef, send_w, clock, kd,
+                    sm[0] if sm else None)
+                buf_t = buf_t.at[clock % D].set(send_t)
             return (last_w, last_t, fresh_w, fresh_t, cache.w, cache.t,
                     cache.ptr, cache.count, buf_w, buf_t, buf_scale, buf_zp,
                     ef, clock + 1), (jnp.sum(gated), jnp.sum(clipped))
@@ -875,11 +884,13 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             sc = jnp.maximum(rslot, 0)
             sub = ModelCache(cache.w[gi], cache.t[gi], cache.ptr[gi],
                              cache.count[gi])
-            msg_w, _ = gather(buf_w, buf_scale, buf_zp, sc, d)
-            lw2, lt2, fw2, ft2, sub2, g2, cl2 = apply_fn(
-                last_w[gi], last_t[gi], fresh_w[gi], fresh_t[gi], sub,
-                msg_w, take(buf_t, sc), vc, Xc[gi], yc[gi],
-                variant=variant, update=update)
+            with jax.named_scope("gather"):
+                msg_w, _ = gather(buf_w, buf_scale, buf_zp, sc, d)
+            with jax.named_scope("receive"):
+                lw2, lt2, fw2, ft2, sub2, g2, cl2 = apply_fn(
+                    last_w[gi], last_t[gi], fresh_w[gi], fresh_t[gi], sub,
+                    msg_w, take(buf_t, sc), vc, Xc[gi], yc[gi],
+                    variant=variant, update=update)
             si = jnp.where(pad, n, gi)        # out of bounds => dropped
             last_w = last_w.at[si].set(lw2, mode="drop")
             last_t = last_t.at[si].set(lt2, mode="drop")
@@ -903,22 +914,25 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
             Xc, yc = records(clock)
             # round 1, dense over all nodes (same math as a K=1 dense apply)
             i0 = jnp.maximum(src0, 0)
-            msg_w0, _ = gather(buf_w, buf_scale, buf_zp, i0[None], d)
-            last_w, last_t, fresh_w, fresh_t, cache, g0, cl0 = apply_fn(
-                last_w, last_t, fresh_w, fresh_t,
-                ModelCache(cw, ct, ptr, cnt), msg_w0,
-                take(buf_t, i0[None]), (src0 >= 0)[None], Xc, yc,
-                variant=variant, update=update)
+            with jax.named_scope("gather"):
+                msg_w0, _ = gather(buf_w, buf_scale, buf_zp, i0[None], d)
+            with jax.named_scope("receive"):
+                last_w, last_t, fresh_w, fresh_t, cache, g0, cl0 = apply_fn(
+                    last_w, last_t, fresh_w, fresh_t,
+                    ModelCache(cw, ct, ptr, cnt), msg_w0,
+                    take(buf_t, i0[None]), (src0 >= 0)[None], Xc, yc,
+                    variant=variant, update=update)
             # rounds >= 2: continue the chain on the multi-receiver subset
             # (their lastModel already holds the round-1 message)
             last_w, last_t, fresh_w, fresh_t, cache, g2, cl2 = subset_apply(
                 (last_w, last_t, fresh_w, fresh_t, cache), ridx, rslot,
                 Xc, yc, buf_w, buf_scale, buf_zp, buf_t)
-            send_w, send_t = corrupt_send(fresh_w, fresh_t, cache, kd)
-            buf_w, buf_scale, buf_zp, ef = send(
-                buf_w, buf_scale, buf_zp, ef, send_w, clock, kd,
-                sm[0] if sm else None)
-            buf_t = buf_t.at[clock % D].set(send_t)
+            with jax.named_scope("send"):
+                send_w, send_t = corrupt_send(fresh_w, fresh_t, cache, kd)
+                buf_w, buf_scale, buf_zp, ef = send(
+                    buf_w, buf_scale, buf_zp, ef, send_w, clock, kd,
+                    sm[0] if sm else None)
+                buf_t = buf_t.at[clock % D].set(send_t)
             return (last_w, last_t, fresh_w, fresh_t, cache.w, cache.t,
                     cache.ptr, cache.count, buf_w, buf_t, buf_scale, buf_zp,
                     ef, clock + 1), (jnp.sum(g0) + g2, jnp.sum(cl0) + cl2)
@@ -998,9 +1012,10 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
                 (last_w, last_t, fresh_w, fresh_t,
                  ModelCache(cw, ct, ptr, cnt)), ridx, rslot,
                 Xc, yc, buf_w, buf_scale, buf_zp, buf_t)
-            buf_w, buf_t, buf_scale, buf_zp, ef = send_compact(
-                buf_w, buf_t, buf_scale, buf_zp, ef, fresh_w, fresh_t,
-                clock, kd, sidx, cache)
+            with jax.named_scope("send"):
+                buf_w, buf_t, buf_scale, buf_zp, ef = send_compact(
+                    buf_w, buf_t, buf_scale, buf_zp, ef, fresh_w, fresh_t,
+                    clock, kd, sidx, cache)
             return (last_w, last_t, fresh_w, fresh_t, cache.w, cache.t,
                     cache.ptr, cache.count, buf_w, buf_t, buf_scale, buf_zp,
                     ef, clock + 1), (g2, cl2)
@@ -1010,7 +1025,8 @@ def _build_chunk_fn(variant: str, learner: str, lam: float, eta: float,
         carry, (g_cycles, cl_cycles) = lax.scan(body, carry,
                                                 (tables, keydata))
         cache = ModelCache(carry[4], carry[5], carry[6], carry[7])
-        errs = evaluate(cache, eval_idx, X_test, y_test)
+        with jax.named_scope("eval"):
+            errs = evaluate(cache, eval_idx, X_test, y_test)
         if emit_streams:
             return carry, (errs, (g_cycles, cl_cycles))
         return carry, (errs, (jnp.sum(g_cycles), jnp.sum(cl_cycles)))
@@ -1103,22 +1119,29 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     scan never observes the hook). The hook must consume the snapshot
     before the next chunk runs — the chunk fn donates its carry.
 
+    Host spans (``repro.core.telemetry.span``), always recorded: ``setup``
+    (``h2d_bytes``), ``key_schedule``, ``stage_draws`` (``d2h_bytes``),
+    per chunk ``route_chunk`` (``sends``, ``deliveries``, ``mode``) with
+    its child ``pack_tables``, ``upload_tables`` (``h2d_bytes``),
+    ``dispatch`` and ``snapshot``, and the final ``collect_results``.
+
     ``telemetry``: optional ``repro.core.telemetry.Telemetry`` — same
     pure-read discipline as ``serve_hook``. Armed runs emit the registered
     per-cycle metric streams (the router's per-cycle message economy, the
     scan's per-cycle gated/clipped counts via the "/telem" chunk-fn
     variant) bitwise-equal to the reference engine's streams under every
-    packing, and record host spans around routing, chunk dispatch,
-    snapshot adoption and the deferred result drain. One armed cost is
-    paid eagerly: the ``_ef`` codecs sync the EF-residual RMS at each
-    eval point (the float read must happen before the next chunk donates
-    the carry); everything else stays pipelined."""
+    packing. One armed cost is paid eagerly: the ``_ef`` codecs sync the
+    EF-residual RMS at each eval point (the float read must happen before
+    the next chunk donates the carry); everything else stays pipelined."""
     n, d = X.shape[0], X.shape[-1]
     D = max(cfg.delay_max_cycles, 1)
     codec = get_codec(cfg.wire_dtype)
-    online_mat, eval_idx, X, y, X_test, y_test = sim_setup(
-        cfg, X, y, X_test, y_test, cycles=cycles, seed=seed,
-        eval_nodes=eval_nodes)
+    with telemetry_mod.span("setup") as sp:
+        setup = sim_setup(cfg, X, y, X_test, y_test, cycles=cycles,
+                          seed=seed, eval_nodes=eval_nodes)
+        sp.counters["h2d_bytes"] = setup_h2d_bytes((X, y, X_test, y_test),
+                                                   setup)
+    online_mat, eval_idx, X, y, X_test, y_test = setup
 
     if use_pallas is None:
         use_pallas = (jax.default_backend() == "tpu"
@@ -1225,10 +1248,12 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     if not pts:                       # cycles == 0: nothing to simulate
         return res
 
-    keys = key_schedule(seed, cycles)
-    # raw uint32 key data for the scan body (the SR quantizer re-derives the
-    # reference engine's per-cycle k_recv from it; DCE'd when not needed)
-    keydata = jnp.asarray(jax.random.key_data(keys))
+    with telemetry_mod.span("key_schedule"):
+        keys = key_schedule(seed, cycles)
+        # raw uint32 key data for the scan body (the SR quantizer re-derives
+        # the reference engine's per-cycle k_recv from it; DCE'd when not
+        # needed)
+        keydata = jnp.asarray(jax.random.key_data(keys))
     router = _HostRouter(n, D)
     bounds = list(zip([0] + pts[:-1], pts))
 
@@ -1245,9 +1270,10 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     # routed, so host memory stays bounded by ~one chunk of draw tables.
     prefetch = cycles * n <= 250_000_000
     if prefetch:
-        with telemetry_mod.maybe_span(tel, "stage_draws", track="control",
-                                      chunks=len(bounds)):
+        with telemetry_mod.span("stage_draws", chunks=len(bounds)) as sp:
             staged = [draw(lo, hi) for lo, hi in bounds]
+            sp.counters["d2h_bytes"] = sum(a.nbytes + b.nbytes
+                                           for a, b in staged)
 
     # compacted-table widths, sticky across chunks (monotone powers of two)
     # so the jitted chunk fn compiles O(log n) times per run, not per chunk
@@ -1326,20 +1352,22 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
                                        + 5 * shards * ws)
         mode = (compact_mode if compact_mode is not None
                 else min(cand, key=cand.get))
-        if mode == "compact":
-            widths["compact"] = wm
-            tables = pack_compact_rounds(win, multi, T, k_rounds, n, wm,
-                                         shards)
-        elif mode == "compact_all":
-            if ws is None:            # forced mode past the near-full gate
-                ws = bucket("send", shard_list_width(senders(), n, shards))
-            widths["compact_all"] = w1
-            widths["send"] = ws
-            tables = (*pack_compact_all(win, recv, T, k_rounds, n, w1,
-                                        shards),
-                      _pack_index_lists(senders(), n, ws, shards))
-        else:
-            tables = (dense_table(win, T, k_rounds, n),)
+        with telemetry_mod.span("pack_tables", mode=mode):
+            if mode == "compact":
+                widths["compact"] = wm
+                tables = pack_compact_rounds(win, multi, T, k_rounds, n, wm,
+                                             shards)
+            elif mode == "compact_all":
+                if ws is None:        # forced mode past the near-full gate
+                    ws = bucket("send",
+                                shard_list_width(senders(), n, shards))
+                widths["compact_all"] = w1
+                widths["send"] = ws
+                tables = (*pack_compact_all(win, recv, T, k_rounds, n, w1,
+                                            shards),
+                          _pack_index_lists(senders(), n, ws, shards))
+            else:
+                tables = (dense_table(win, T, k_rounds, n),)
         if codec.ef and mode != "compact_all":
             # the EF residual refreshes exactly where the reference
             # engine's send_ok holds == where a message entered the pending
@@ -1348,27 +1376,37 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             tables = (*tables, an >= 0)
         return mode, tables, stats
 
+    def routed(i):
+        """``route(i)`` in its span, with the chunk's message counts."""
+        with telemetry_mod.span("route_chunk", chunk=i) as sp:
+            out = route(i)
+            sp.counters.update(sends=out[2]["sent"],
+                               deliveries=out[2]["delivered"], mode=out[0])
+        return out
+
     msg_bytes = message_wire_bytes(d, cfg.wire_dtype)
     in_flight = 0
     errs_pending = []
-    with telemetry_mod.maybe_span(tel, "route_chunk", track="control",
-                                  chunk=0):
-        pending = route(0)
+    pending = routed(0)
     for i, p in enumerate(pts):
         lo, hi = bounds[i]
         mode, tables, stats = pending
-        with telemetry_mod.maybe_span(tel, "chunk_dispatch", track="device",
-                                      chunk=i, mode=mode, cycles=hi - lo):
+        with telemetry_mod.span("upload_tables", chunk=i) as sp:
+            dev_tables = tuple(jnp.asarray(a) for a in tables)
+            sp.counters["h2d_bytes"] = sum(int(a.nbytes) for a in tables)
+        with telemetry_mod.span("dispatch", chunk=i, cycles=hi - lo):
             carry, (errs, fstats) = get_chunk_fn(mode)(
-                carry, tuple(jnp.asarray(a) for a in tables), keydata[lo:hi],
-                X, y, X_test, y_test, eval_idx, byz)
+                carry, dev_tables, keydata[lo:hi], X, y, X_test, y_test,
+                eval_idx, byz)
+        # no reference past the dispatch: the tables are freed as soon as
+        # the chunk is done with them, not when the next chunk's arrive
+        del dev_tables
         if serve_hook is not None:
             # pure read of the fresh carry, dispatched before the next
             # chunk donates it; the scan never observes the hook, so the
             # run is bitwise identical with or without serving
             from repro.core import serving
-            with telemetry_mod.maybe_span(tel, "snapshot", track="serving",
-                                          cycle=p):
+            with telemetry_mod.span("snapshot", cycle=p):
                 serve_hook(p, serving.snapshot_from_carry(carry))
         if armed:
             # the one eager armed read: the EF-residual RMS must be taken
@@ -1376,9 +1414,7 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             # non-EF codecs — carry[12] is the empty (0, 0) lane)
             tel.emit("ef_residual_rms", ef_residual_norm(carry[12]))
         if i + 1 < len(pts):
-            with telemetry_mod.maybe_span(tel, "route_chunk",
-                                          track="control", chunk=i + 1):
-                pending = route(i + 1)   # overlaps the in-flight device scan
+            pending = routed(i + 1)   # overlaps the in-flight device scan
         res.sent_total += stats["sent"]
         res.delivered_total += stats["delivered"]
         res.lost_total += stats["lost"]
@@ -1408,8 +1444,7 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
                 multi_nodes=stats["multi_sizes"],
                 online_nodes=online_mat[lo:hi].sum(axis=1),
                 corrupted=stats["corrupted_cycles"])
-    with telemetry_mod.maybe_span(tel, "collect_results", track="device",
-                                  chunks=len(errs_pending)):
+    with telemetry_mod.span("collect_results", chunks=len(errs_pending)):
         for (err_f, err_v, sim), (g, cl) in errs_pending:
             res.err_fresh.append(float(err_f))
             res.err_voted.append(float(err_v))

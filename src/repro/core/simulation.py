@@ -455,6 +455,9 @@ class SimResult:
     # independent count, so ``sent_total == delivered_total + lost_total
     # + overflow_total + in_flight`` checks the message economy
     in_flight: int = 0
+    # the run's span record (repro.core.telemetry.RunRecord): the engine's
+    # spans and compile events under the root span "run"
+    trace: Optional[telemetry_mod.RunRecord] = None
 
 
 def ef_residual_norm(ef) -> float:
@@ -514,6 +517,16 @@ def sim_setup(cfg: GossipLinearConfig, X, y, X_test, y_test, *, cycles: int,
     return (online_mat, jnp.asarray(eval_idx),
             jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32),
             jnp.asarray(X_test, jnp.float32), jnp.asarray(y_test, jnp.float32))
+
+
+def setup_h2d_bytes(inputs, setup) -> int:
+    """Bytes :func:`sim_setup` copied host to device: the eval subset, and
+    each of ``inputs`` (X, y, X_test, y_test) that came in as a host
+    array; ``setup`` is what it returned."""
+    eval_idx, *data = setup[1:]
+    return int(eval_idx.nbytes) + sum(
+        int(out.nbytes) for inp, out in zip(inputs, data)
+        if isinstance(inp, np.ndarray))
 
 
 def eval_points(cycles: int, eval_every: int) -> List[int]:
@@ -586,33 +599,56 @@ def run_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     perturb the run: with or without a hook, the curves are bitwise
     identical (tests/test_serving.py).
 
+    Every run records its host spans (``repro.core.telemetry.span``:
+    set-up, routing, copies, dispatch, snapshots, serving, compiles) under
+    a root span ``run``, returned as ``SimResult.trace`` and kept in the
+    telemetry module's ring of recent runs.
+
     ``telemetry``: optional :class:`repro.core.telemetry.Telemetry`. When
     armed, both engines emit the registered per-cycle metric streams
     (``METRIC_STREAMS``: message economy, wire bytes, occupancy, fault
-    counters, EF residual RMS, online fraction) and record host spans
-    around the cycle dispatch, eval and snapshot phases. Same discipline
-    as ``serve_hook``: a pure read — armed and unarmed runs are bitwise
-    identical (tests/test_telemetry.py), and ``telemetry=None`` compiles
-    the exact pre-telemetry programs (docs/OBSERVABILITY.md).
+    counters, EF residual RMS, online fraction), and the object keeps the
+    run's record for its reports. Same discipline as ``serve_hook``: a
+    pure read — armed and unarmed runs are bitwise identical
+    (tests/test_telemetry.py), and ``telemetry=None`` compiles the exact
+    pre-telemetry programs (docs/OBSERVABILITY.md).
     """
-    if engine == "sharded":
-        from repro.core.sharded_engine import run_sharded_simulation
-        return run_sharded_simulation(
-            cfg, X, y, X_test, y_test, cycles=cycles, eval_every=eval_every,
-            seed=seed, eval_nodes=eval_nodes, sampler=sampler,
-            k_rounds=k_rounds, serve_hook=serve_hook, telemetry=telemetry,
-            **engine_kwargs)
-    if engine != "reference":
+    if engine not in ("reference", "sharded"):
         raise ValueError(f"unknown engine {engine!r} "
                          "(expected 'reference' or 'sharded')")
-    if engine_kwargs:
+    if engine == "reference" and engine_kwargs:
         raise TypeError("unexpected keyword arguments for the reference "
                         f"engine: {sorted(engine_kwargs)}")
+    with telemetry_mod.run(engine, telemetry, n=int(X.shape[0]),
+                           cycles=int(cycles)) as record:
+        if engine == "sharded":
+            from repro.core.sharded_engine import run_sharded_simulation
+            res = run_sharded_simulation(
+                cfg, X, y, X_test, y_test, cycles=cycles,
+                eval_every=eval_every, seed=seed, eval_nodes=eval_nodes,
+                sampler=sampler, k_rounds=k_rounds, serve_hook=serve_hook,
+                telemetry=telemetry, **engine_kwargs)
+        else:
+            res = _run_reference(
+                cfg, X, y, X_test, y_test, cycles=cycles,
+                eval_every=eval_every, seed=seed, eval_nodes=eval_nodes,
+                sampler=sampler, k_rounds=k_rounds, serve_hook=serve_hook,
+                telemetry=telemetry)
+    res.trace = record
+    return res
 
+
+def _run_reference(cfg, X, y, X_test, y_test, *, cycles, eval_every, seed,
+                   eval_nodes, sampler, k_rounds, serve_hook, telemetry):
+    """The reference engine's loop over cycles (``run_simulation``'s
+    ``engine="reference"``)."""
     n, d = X.shape[0], X.shape[-1]
-    online_mat, eval_idx, X, y, X_test, y_test = sim_setup(
-        cfg, X, y, X_test, y_test, cycles=cycles, seed=seed,
-        eval_nodes=eval_nodes)
+    with telemetry_mod.span("setup") as sp:
+        setup = sim_setup(cfg, X, y, X_test, y_test, cycles=cycles,
+                          seed=seed, eval_nodes=eval_nodes)
+        sp.counters["h2d_bytes"] = setup_h2d_bytes((X, y, X_test, y_test),
+                                                   setup)
+    online_mat, eval_idx, X, y, X_test, y_test = setup
 
     D = max(cfg.delay_max_cycles, 1)
     state = init_state(n, d, cfg.cache_size, D, wire_dtype=cfg.wire_dtype)
@@ -634,7 +670,7 @@ def run_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     in_flight = 0
     for c in range(cycles):
         key, sub = jax.random.split(key)
-        with telemetry_mod.maybe_span(tel, "cycle", track="device", cycle=c):
+        with telemetry_mod.span("cycle", cycle=c):
             state, stats = simulate_cycle(
                 state, X, y, jnp.asarray(online_mat[c]), sub, byz,
                 variant=cfg.variant, learner=cfg.learner, lam=cfg.lam,
@@ -668,8 +704,7 @@ def run_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
                 corrupted=int(stats["corrupted"]),
                 gated=int(stats["gated"]), clipped=int(stats["clipped"]))
         if (c + 1) % eval_every == 0 or c == cycles - 1:
-            with telemetry_mod.maybe_span(tel, "eval", track="eval",
-                                          cycle=c + 1):
+            with telemetry_mod.span("eval", cycle=c + 1):
                 err_f, err_v, sim = _eval(state.cache, eval_idx, X_test,
                                           y_test)
                 res.cycles.append(c + 1)
@@ -680,8 +715,7 @@ def run_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
                 tel.emit("ef_residual_rms", ef_residual_norm(state.ef))
             if serve_hook is not None:
                 from repro.core import serving
-                with telemetry_mod.maybe_span(tel, "snapshot",
-                                              track="serving", cycle=c + 1):
+                with telemetry_mod.span("snapshot", cycle=c + 1):
                     serve_hook(c + 1, serving.take_snapshot(state))
     res.wire_bytes_total = res.sent_total * message_wire_bytes(d, cfg.wire_dtype)
     res.ef_residual_norm = ef_residual_norm(state.ef)

@@ -1,50 +1,62 @@
-"""Protocol telemetry: metric streams, host spans, latency histograms.
+"""Protocol telemetry: spans, metric streams, latency histograms.
 
 The paper's experimental story (Figs. 1-7) is entirely about *observing* a
 running protocol — error curves, message economies, convergence under
 churn. This module is the one home for that observability, with three
 faces:
 
+* **Spans, always on** — :func:`span` records a named interval of host
+  code on ``time.perf_counter_ns``'s clock, with its parent (the span open
+  when it started) and its counters (bytes copied, messages routed, rows
+  served), and writes the same interval into the profiler's trace as the
+  annotation ``gossip/<name>``. Every ``run_simulation`` call opens a run
+  record (:func:`run`) whose root span ``run`` holds the engine's spans;
+  the records of recent runs stay in a ring (:func:`runs`), and the last
+  run that started under a profiler session stays reachable on its own
+  (:func:`last_profiled`), which is how a reader of a profile finds the
+  program's spans. Each XLA backend compile is recorded as a ``compile``
+  span with its ``fun_name``, under the span open when it ended.
 * **Per-cycle metric streams** — ``METRIC_STREAMS`` is a registered schema
   (same both-ways docs-gate philosophy as ``WIRE_CODECS``/``FAULT_MODELS``:
   the table in docs/OBSERVABILITY.md is cross-checked against this registry
-  by ``tools/check_docs.py``) of the series both engines emit identically:
-  the message economy (the PR 1 balance invariant, continuously emitted),
-  wire bytes, receiver occupancy, fault counters, EF residual RMS and the
-  online fraction. Because the reference engine and all three sharded
-  packings emit the same numbers, the metric stream itself is a
-  cross-engine parity surface (tests/test_telemetry.py).
-* **Host spans** — ``telemetry.span("route_chunk")`` wraps the control
-  plane, scan dispatch, snapshot adoption and serving batch assembly with
-  wall-clock timing plus a jit compile-count delta per span (via the
-  engines' compile caches — ``retrace_counts()`` and ``_cache_size()``),
-  exported as Chrome trace-event JSON (:meth:`Telemetry.export_chrome_trace`)
-  viewable in Perfetto and summarized by ``tools/trace_report.py``.
+  by ``tools/check_docs.py``) of the series both engines emit identically
+  when a :class:`Telemetry` is armed: the message economy (its
+  balance invariant, continuously emitted), wire bytes, receiver
+  occupancy, fault counters, EF residual RMS and the online fraction.
+  Because the reference engine and all three sharded packings emit the
+  same numbers, the metric stream itself is a cross-engine parity surface
+  (tests/test_telemetry.py). An armed :class:`Telemetry` also keeps the
+  run records of its runs and exports them as Chrome trace-event JSON
+  (:meth:`Telemetry.export_chrome_trace`), summarized offline by
+  ``tools/trace_report.py``.
 * **Latency histograms** — :class:`LatencyHistogram` is the fixed-bucket
   log-scale histogram behind every latency percentile in the repo
   (``GossipServer`` batch latency, ``BENCH_serving.json`` p50/p90/p99/p999),
   replacing ad-hoc per-call percentile math.
 
 The hard contract (docs/CONTRACTS.md): **telemetry is a pure read**.
-``telemetry=None`` (the default everywhere) compiles to the exact pre-
-telemetry engines — the armed collection paths are statically gated, the
-same mechanism as the fault machinery — and an armed :class:`Telemetry`
-must leave error curves and all protocol state bitwise identical on both
-engines. Telemetry never touches ``jax.random``: spans and histograms use
-``time.perf_counter`` and streams are integer/float *reads* of state the
-engines already computed, so the pinned threefry chain
-(tools/lint/rng_allowlist.py) cannot shift.
+Spans run outside every jitted program and change none. ``telemetry=None``
+(the default everywhere) compiles to the exact pre-telemetry engines — the
+armed stream paths are statically gated, the same mechanism as the fault
+machinery — and an armed :class:`Telemetry` must leave error curves and
+all protocol state bitwise identical on both engines. Telemetry never
+touches ``jax.random``: spans read the host clock and streams are
+integer/float *reads* of state the engines already computed, so the
+pinned threefry chain (tools/lint/rng_allowlist.py) cannot shift.
 """
 from __future__ import annotations
 
+import itertools
 import json
-import sys
+import threading
 import time
-from contextlib import nullcontext
+from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 # ---------------------------------------------------------------------------
@@ -112,91 +124,231 @@ METRIC_STREAMS: Dict[str, MetricStream] = dict([
 
 
 # ---------------------------------------------------------------------------
-# host spans
+# spans: always on, on the profiler's clock
 # ---------------------------------------------------------------------------
 
-# span tracks become named Perfetto threads; the index is the trace tid
+# span tracks become named Perfetto threads in the Chrome export
 TRACKS: Tuple[str, ...] = ("host", "control", "device", "serving", "eval")
 
-# span naming convention (docs/OBSERVABILITY.md): snake_case verbs naming
-# the phase, stable across PRs so trace diffs stay meaningful
-SPAN_NAMES = {
-    "route_chunk":    "control — host winner routing for one chunk",
-    "stage_draws":    "control — upfront device draws for all chunks",
-    "chunk_dispatch": "device — dispatch one data-plane scan chunk",
-    "cycle":          "device — one reference-engine cycle (dispatch+sync)",
-    "eval":           "eval — population error at an eval point",
-    "collect_results": "device — drain deferred eval results (sync point)",
-    "snapshot":       "serving — snapshot build + serve_hook call",
-    "snapshot_adopt": "serving — GossipServer adopts a snapshot (sync)",
-    "serve_batch":    "serving — assemble + answer one query batch",
+# span name -> (track, phase). The names are a contract: the chip
+# benchmark's readers find spans by them, so span() refuses any other name
+SPAN_NAMES: Dict[str, Tuple[str, str]] = {
+    "run": ("host", "one run_simulation call, either engine (the root)"),
+    "setup": ("host", "churn trace, eval subset, upload of the data"),
+    "key_schedule": ("control", "the per-cycle threefry subkeys"),
+    "stage_draws": ("control", "device draws of every chunk, copied to "
+                               "the host"),
+    "route_chunk": ("control", "host winner routing for one chunk"),
+    "pack_tables": ("control", "the chosen packing's routing tables"),
+    "upload_tables": ("control", "host-to-device copy of one chunk's "
+                                 "tables"),
+    "dispatch": ("device", "dispatch of one chunk's scan program"),
+    "collect_results": ("device", "drain of the deferred eval results "
+                                  "(the sync point)"),
+    "cycle": ("device", "one reference-engine cycle (dispatch + sync)"),
+    "eval": ("eval", "population error at an eval point (reference)"),
+    "snapshot": ("serving", "snapshot build + serve_hook call"),
+    "snapshot_adopt": ("serving", "GossipServer waits for and adopts a "
+                                  "snapshot"),
+    "submit": ("serving", "GossipServer.submit"),
+    "flush": ("serving", "GossipServer.flush"),
+    "serve_batch": ("serving", "assemble + answer one query batch"),
+    "serve_device": ("serving", "one batch from dispatch to answers "
+                                "ready"),
+    "compile": ("host", "one XLA backend compile (or persistent-cache "
+                        "load)"),
 }
 
-
-def compile_cache_sizes() -> int:
-    """Total jit compile-cache entries across the repo's hot-path fns.
-
-    The per-span delta of this number is the span's "compiles" count —
-    the same counters ``tools/lint/retrace_guard.py`` budgets. Reads via
-    ``sys.modules`` so telemetry never forces an engine import."""
-    total = 0
-    sim = sys.modules.get("repro.core.simulation")
-    if sim is not None:
-        total += sim.simulate_cycle._cache_size()
-        total += sim._eval._cache_size()
-    sh = sys.modules.get("repro.core.sharded_engine")
-    if sh is not None:
-        total += sum(sh.retrace_counts().values())
-    srv = sys.modules.get("repro.core.serving")
-    if srv is not None:
-        total += (srv.serve_fresh._cache_size()
-                  + srv.serve_voted._cache_size()
-                  + srv.serve_voted_kernel._cache_size())
-    return total
+# profiler annotations carry this prefix, so no program span shares a name
+# with an annotation written around the program from outside
+ANNOTATION_PREFIX = "gossip/"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+RUNS_KEPT = 64          # run records kept (the ring)
+LOOSE_KEPT = 4096       # spans recorded outside any run
 
 
-@dataclass
 class Span:
-    """One finished host span (relative perf_counter seconds)."""
-    name: str
-    track: str
-    t0: float
-    t1: float
-    compiles: int
-    args: Dict[str, object]
+    """One span on ``time.perf_counter_ns``'s clock: ``[t0_ns, t1_ns)``
+    (``t1_ns`` is None while it is open), the id of the span open when it
+    started (None for a root), the id of its run (None outside any run),
+    and its counters."""
+    __slots__ = ("id", "parent", "run", "name", "t0_ns", "t1_ns",
+                 "counters")
+
+    def __init__(self, name: str, counters: Dict[str, object]):
+        self.name, self.counters = name, counters
+        self.id = self.parent = self.run = self.t0_ns = self.t1_ns = None
 
     @property
     def seconds(self) -> float:
-        return self.t1 - self.t0
+        return (self.t1_ns - self.t0_ns) / 1e9
+
+    @property
+    def track(self) -> str:
+        return SPAN_NAMES[self.name][0]
+
+    def __repr__(self):
+        return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"run={self.run}, {self.t0_ns}-{self.t1_ns}, "
+                f"{self.counters})")
 
 
-class _SpanCtx:
-    __slots__ = ("tel", "name", "track", "args", "_t0", "_c0")
+@dataclass
+class RunRecord:
+    """What one ``run_simulation`` call recorded: its spans (the root
+    ``run`` first, the others in the order they opened, a ``compile`` span
+    when its compile ended) and whether a profiler session was on when the
+    run started (``profiled``)."""
+    id: int
+    engine: str
+    profiled: bool
+    spans: List[Span] = field(default_factory=list)
 
-    def __init__(self, tel: "Telemetry", name: str, track: str, args):
-        self.tel, self.name, self.track, self.args = tel, name, track, args
+    def named(self, name: str) -> List[Span]:
+        return [s for s in self.spans if s.name == name]
 
-    def __enter__(self):
-        self._c0 = compile_cache_sizes()
-        self._t0 = time.perf_counter()
-        return self
+    def seconds(self, name: str) -> float:
+        """Total seconds of the finished spans called ``name``."""
+        return sum(s.seconds for s in self.named(name)
+                   if s.t1_ns is not None)
+
+    def total(self, name: str, counter: str):
+        """Sum of one counter over the spans called ``name``."""
+        return sum(s.counters.get(counter, 0) for s in self.named(name))
+
+
+class _Recorder:
+    """The process's span record: a ring of recent run records, the last
+    run that ran under a profiler session, the spans opened outside any
+    run, and a stack of open spans per thread."""
+
+    def __init__(self):
+        self.runs: deque = deque(maxlen=RUNS_KEPT)
+        self.loose: deque = deque(maxlen=LOOSE_KEPT)
+        self.last_profiled: Optional[RunRecord] = None
+        self.ids = itertools.count(1)
+        self.local = threading.local()
+        self.listening = False
+
+    def stack(self) -> list:
+        """Open ``(span, run record)`` pairs of this thread, innermost
+        last."""
+        try:
+            return self.local.stack
+        except AttributeError:
+            self.local.stack = []
+            return self.local.stack
+
+    def place(self, s: Span, record: Optional[RunRecord],
+              stack) -> Optional[RunRecord]:
+        """Give ``s`` its id, parent and run, file it, and return its run
+        record (None outside any run)."""
+        parent, rec = stack[-1] if stack else (None, None)
+        if record is not None:
+            parent, rec = None, record
+        s.id = next(self.ids)
+        s.parent = parent.id if parent is not None else None
+        s.run = rec.id if rec is not None else None
+        (rec.spans if rec is not None else self.loose).append(s)
+        return rec
+
+    def listen(self) -> None:
+        """Record every backend compile as a ``compile`` span from now on."""
+        if not self.listening:
+            self.listening = True
+            jax.monitoring.register_event_duration_secs_listener(
+                self.on_duration)
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        t1 = time.perf_counter_ns()
+        s = Span("compile", {"fun_name": str(kw.get("fun_name", "")),
+                             "seconds": float(duration)})
+        self.place(s, None, self.stack())
+        s.t0_ns, s.t1_ns = t1 - int(duration * 1e9), t1
+
+
+_REC = _Recorder()
+
+
+class _Open:
+    """The context of one span: opens it on the span stack and in the
+    profiler (``TraceAnnotation("gossip/<name>")``), closes both."""
+    __slots__ = ("span", "record", "ann")
+
+    def __init__(self, name: str, counters, record=None):
+        if name not in SPAN_NAMES:
+            raise ValueError(f"unregistered span name {name!r} "
+                             f"(registered: {sorted(SPAN_NAMES)})")
+        self.span = Span(name, counters)
+        self.record = record
+
+    def __enter__(self) -> Span:
+        _REC.listen()
+        stack = _REC.stack()
+        s = self.span
+        rec = _REC.place(s, self.record, stack)
+        stack.append((s, rec))
+        self.ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + s.name)
+        self.ann.__enter__()
+        s.t0_ns = time.perf_counter_ns()
+        return s
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self.tel.spans.append(Span(
-            self.name, self.track, self._t0 - self.tel._origin,
-            t1 - self.tel._origin, compile_cache_sizes() - self._c0,
-            self.args))
+        s = self.span
+        s.t1_ns = time.perf_counter_ns()
+        if s.counters and jax.profiler.TraceAnnotation.is_enabled():
+            self.ann.set_metadata(**{k: v for k, v in s.counters.items()
+                                     if isinstance(v, (int, float, str))})
+        self.ann.__exit__(*exc)
+        stack = _REC.stack()
+        while stack and stack.pop()[0] is not s:
+            pass
         return False
 
 
-def maybe_span(tel: Optional["Telemetry"], name: str, track: str = "host",
-               **args):
-    """``tel.span(...)`` when armed, a free ``nullcontext`` when not — the
-    one-liner the engines use so the unarmed hot path stays untouched."""
-    if tel is None:
-        return nullcontext()
-    return tel.span(name, track=track, **args)
+def span(name: str, **counters) -> _Open:
+    """Record ``name`` around a block, always: ``with span("route_chunk",
+    chunk=i) as s: ...; s.counters["mode"] = mode``. The span's parent is
+    the span open when it started; inside a run (:func:`run`) it joins
+    that run's record. The same interval is a profiler annotation named
+    ``gossip/<name>``. Counters are numbers or strings, set at the start
+    or while the span is open."""
+    return _Open(name, counters)
+
+
+@contextmanager
+def run(engine: str, telemetry: Optional["Telemetry"] = None, **counters):
+    """Open a run record and its root span ``run``; yields the record.
+
+    The record joins the ring (:func:`runs`); a run that starts under a
+    profiler session becomes :func:`last_profiled` too, and an armed
+    ``telemetry`` keeps it for its reports."""
+    rec = RunRecord(next(_REC.ids), engine,
+                    jax.profiler.TraceAnnotation.is_enabled())
+    _REC.runs.append(rec)
+    if rec.profiled:
+        _REC.last_profiled = rec
+    if telemetry is not None:
+        telemetry.runs.append(rec)
+    with _Open("run", counters, record=rec):
+        yield rec
+
+
+def runs() -> List[RunRecord]:
+    """The recent run records, oldest first (at most ``RUNS_KEPT``)."""
+    return list(_REC.runs)
+
+
+def last_profiled() -> Optional[RunRecord]:
+    """The last run that started while a profiler session was on."""
+    return _REC.last_profiled
+
+
+def loose_spans() -> List[Span]:
+    """Spans recorded outside any run, oldest first."""
+    return list(_REC.loose)
 
 
 # ---------------------------------------------------------------------------
@@ -303,41 +455,6 @@ class LatencyHistogram:
 
 
 # ---------------------------------------------------------------------------
-# shared wall-clock helpers (the one home for bench timing)
-# ---------------------------------------------------------------------------
-
-
-class Timer:
-    """Context-manager wall clock; ``.s`` holds elapsed seconds.
-
-    The single Timer the benchmarks use (re-exported by
-    ``benchmarks/common.py``) — perf_counter-based, monotonic."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *a):
-        self.s = time.perf_counter() - self.t0
-        return False
-
-
-def best_of(fn, repeats: int = 2):
-    """Min-time estimator: call ``fn()`` ``repeats`` times.
-
-    Returns ``(best_seconds, all_seconds, last_result)``. Shared-container
-    noise is strictly additive, so the minimum is the estimator every
-    bench uses (previously copy-pasted per bench as a secs list + min)."""
-    secs: List[float] = []
-    result = None
-    for _ in range(max(repeats, 1)):
-        with Timer() as t:
-            result = fn()
-        secs.append(t.s)
-    return min(secs), secs, result
-
-
-# ---------------------------------------------------------------------------
 # the Telemetry object
 # ---------------------------------------------------------------------------
 
@@ -347,22 +464,22 @@ class Telemetry:
 
     Pass as ``run_simulation(..., telemetry=tel)`` (either engine) and/or
     ``GossipServer(telemetry=tel)``. Collects the registered metric
-    streams, host spans and latency histograms; export with
+    streams and latency histograms and keeps the run records of the runs
+    it was armed for (their spans: :attr:`spans`); export with
     :meth:`export_chrome_trace`, summarize with :meth:`phase_report` or
     ``tools/trace_report.py`` on the exported file.
 
     Arming one Telemetry across several sequential runs is supported —
-    spans share one wall-clock origin and stream segments concatenate in
-    run order (each run's ``in_flight`` balance restarts from zero at its
-    own first cycle)."""
+    spans share one clock and stream segments concatenate in run order
+    (each run's ``in_flight`` balance restarts from zero at its own first
+    cycle)."""
 
     def __init__(self, label: str = ""):
         self.label = label
         self.streams: Dict[str, List] = {n: [] for n in METRIC_STREAMS}
-        self.spans: List[Span] = []
+        self.runs: List[RunRecord] = []
         self.histograms: Dict[str, LatencyHistogram] = {}
         self.annotations: Dict[str, object] = {}
-        self._origin = time.perf_counter()
 
     # ------------------------------------------------------------- streams
     def emit(self, name: str, values) -> None:
@@ -389,17 +506,15 @@ class Telemetry:
         return np.asarray(self.streams[name],
                           np.float64 if kind == "float" else np.int64)
 
-    # --------------------------------------------------------------- spans
-    def span(self, name: str, track: str = "host", **args) -> _SpanCtx:
-        if track not in TRACKS:
-            raise ValueError(f"unknown span track {track!r} "
-                             f"(expected one of {TRACKS})")
-        return _SpanCtx(self, name, track, args)
-
     def histogram(self, name: str) -> LatencyHistogram:
         return self.histograms.setdefault(name, LatencyHistogram())
 
-    # ------------------------------------------------------------ reports
+    # --------------------------------------------------------------- spans
+    @property
+    def spans(self) -> List[Span]:
+        """The finished spans of the armed runs, run by run."""
+        return [s for r in self.runs for s in r.spans if s.t1_ns is not None]
+
     def phase_seconds(self) -> Dict[str, float]:
         """Total span seconds per span name (the per-phase summary)."""
         out: Dict[str, float] = {}
@@ -408,32 +523,40 @@ class Telemetry:
         return out
 
     def compile_total(self) -> int:
-        return sum(s.compiles for s in self.spans)
+        return sum(s.name == "compile" for s in self.spans)
 
     def wall_seconds(self) -> float:
-        if not self.spans:
+        spans = self.spans
+        if not spans:
             return 0.0
-        return (max(s.t1 for s in self.spans)
-                - min(s.t0 for s in self.spans))
+        return (max(s.t1_ns for s in spans)
+                - min(s.t0_ns for s in spans)) / 1e9
 
+    # ------------------------------------------------------------ reports
     def phase_report(self) -> str:
         """Printable per-phase table (what the ``--trace`` example flags
         show; the standalone equivalent over an exported file is
         ``tools/trace_report.py``)."""
         wall = self.wall_seconds()
-        lines = [f"telemetry: {len(self.spans)} spans, "
+        spans = self.spans
+        lines = [f"telemetry: {len(spans)} spans, "
                  f"{self.compile_total()} jit compiles, "
                  f"{wall:.3f}s spanned wall clock"]
         counts: Dict[str, int] = {}
-        compiles: Dict[str, int] = {}
-        for s in self.spans:
+        for s in spans:
             counts[s.name] = counts.get(s.name, 0) + 1
-            compiles[s.name] = compiles.get(s.name, 0) + s.compiles
         for name, secs in sorted(self.phase_seconds().items(),
                                  key=lambda kv: -kv[1]):
             pct = 100.0 * secs / wall if wall > 0 else 0.0
             lines.append(f"  {name:<16} {secs:>9.3f}s {pct:>5.1f}%  "
-                         f"x{counts[name]:<5d} compiles={compiles[name]}")
+                         f"x{counts[name]}")
+        by_id = {s.id: s for s in spans}
+        for s in spans:
+            if s.name == "compile":
+                under = by_id.get(s.parent)
+                lines.append(f"  compile {s.counters['fun_name']} "
+                             f"{s.seconds * 1e3:.1f}ms under "
+                             f"{under.name if under else '-'}")
         sent = self.stream_array("sent")
         wb = self.stream_array("wire_bytes")
         if sent.size:
@@ -451,8 +574,8 @@ class Telemetry:
     def export_chrome_trace(self, path) -> Path:
         """Write Chrome trace-event JSON (the ``chrome://tracing`` /
         Perfetto "JSON" flavor): one complete ("X") event per span on a
-        named thread per track, an instant event per span that triggered
-        jit compiles, and the per-cycle metric streams as counter ("C")
+        named thread per track (a ``compile`` span carries its
+        ``fun_name``), and the per-cycle metric streams as counter ("C")
         events on a synthetic pid=1 timeline where 1 cycle == 1
         microsecond (protocol time, not wall time — labeled as such).
         Streams, histograms and annotations ride in ``otherData`` so
@@ -467,19 +590,17 @@ class Telemetry:
         for tid, track in enumerate(TRACKS):
             events.append({"ph": "M", "pid": 0, "tid": tid,
                            "name": "thread_name", "args": {"name": track}})
-        for s in self.spans:
-            tid = TRACKS.index(s.track)
+        spans = self.spans
+        origin = min((s.t0_ns for s in spans), default=0)
+        for s in spans:
             args = {k: (v if isinstance(v, (int, float, str, bool))
-                        else str(v)) for k, v in s.args.items()}
-            args["compiles"] = s.compiles
-            events.append({"ph": "X", "pid": 0, "tid": tid, "name": s.name,
-                           "ts": s.t0 * 1e6, "dur": s.seconds * 1e6,
+                        else str(v)) for k, v in s.counters.items()}
+            args.update(id=s.id, parent=s.parent, run=s.run)
+            events.append({"ph": "X", "pid": 0,
+                           "tid": TRACKS.index(s.track), "name": s.name,
+                           "ts": (s.t0_ns - origin) / 1e3,
+                           "dur": (s.t1_ns - s.t0_ns) / 1e3,
                            "args": args, "cat": s.track})
-            if s.compiles:
-                events.append({"ph": "i", "pid": 0, "tid": tid,
-                               "name": f"jit compile x{s.compiles}",
-                               "ts": s.t0 * 1e6, "s": "t",
-                               "cat": "compile"})
         for name, spec in METRIC_STREAMS.items():
             if spec.cadence != "cycle":
                 continue

@@ -14,19 +14,20 @@ batch is answered immediately (node assignment by the configured policy,
 then ``serve_voted`` / ``serve_voted_kernel``, optionally ``serve_fresh``
 alongside for the fresh-vs-voted comparison); ``flush()`` pads the tail to
 the batch shape — one compiled signature per (N, batch) — and slices the
-answers back. Per-batch latency is measured around the predict dispatch
-with the answer blocked to completion and recorded into the shared
-fixed-bucket :class:`repro.core.telemetry.LatencyHistogram`; ``stats()``
-aggregates queries/s and p50/p90/p99/p999 batch latency from it (the same
-histogram BENCH_serving.json dumps bucket-wise). Pass ``telemetry=`` to
-additionally record snapshot-adoption and batch-assembly spans on the
-"serving" trace track.
+answers back. Per-batch latency is the ``serve_device`` span around the
+predict dispatch with the answer blocked to completion, recorded into the
+shared fixed-bucket :class:`repro.core.telemetry.LatencyHistogram`;
+``stats()`` aggregates queries/s and p50/p90/p99/p999 batch latency from
+it (the same histogram BENCH_serving.json dumps bucket-wise). Every call
+records its span (``snapshot_adopt``, ``submit``, ``flush``,
+``serve_batch``, ``serve_device``: ``repro.core.telemetry.span``), inside
+the engine's run record when a hook calls it; ``telemetry=`` shares the
+histogram into an armed :class:`repro.core.telemetry.Telemetry`.
 
     PYTHONPATH=src python examples/serve_batched.py    # end-to-end driver
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -106,8 +107,7 @@ class GossipServer:
         snapshot, blocking until the engine materialized EVERY leaf (the
         cache tensor dominates at large N) — so the batch latency below
         measures serving, not leftover simulation compute."""
-        with telemetry_mod.maybe_span(self.telemetry, "snapshot_adopt",
-                                      track="serving", cycle=int(cycle)):
+        with telemetry_mod.span("snapshot_adopt", cycle=int(cycle)):
             jax.block_until_ready(snapshot)
             self.snapshot = snapshot
             self.snapshot_cycle = int(cycle)
@@ -116,28 +116,31 @@ class GossipServer:
     def submit(self, X) -> None:
         """Accumulate queries (rows of X); answer every full batch."""
         X = np.asarray(X, np.float32)
-        for row in X:
-            self._pending_x.append(row)
-            self._pending_ids.append(self._next_id)
-            self._next_id += 1
-            if len(self._pending_x) >= self.batch_size:
-                self._serve_pending()
+        with telemetry_mod.span("submit", rows=len(X)):
+            for row in X:
+                self._pending_x.append(row)
+                self._pending_ids.append(self._next_id)
+                self._next_id += 1
+                if len(self._pending_x) >= self.batch_size:
+                    self._serve_pending()
 
     def flush(self) -> None:
         """Answer the partial tail batch (padded to the compiled shape)."""
-        if self._pending_x:
-            self._serve_pending()
+        with telemetry_mod.span("flush", rows=len(self._pending_x)):
+            if self._pending_x:
+                self._serve_pending()
 
     def _serve_pending(self) -> None:
-        with telemetry_mod.maybe_span(self.telemetry, "serve_batch",
-                                      track="serving"):
-            self._serve_pending_inner()
-
-    def _serve_pending_inner(self) -> None:
         if self.snapshot is None:
             raise RuntimeError("no snapshot yet — wire serve_hook into "
                                "run_simulation before submitting queries")
         k = min(len(self._pending_x), self.batch_size)
+        with telemetry_mod.span("serve_batch", queries=k,
+                                padded=self.batch_size - k,
+                                batch=len(self.batches)):
+            self._serve_batch(k)
+
+    def _serve_batch(self, k: int) -> None:
         xb = np.stack(self._pending_x[:k])
         ids = np.asarray(self._pending_ids[:k])
         del self._pending_x[:k], self._pending_ids[:k]
@@ -155,14 +158,14 @@ class GossipServer:
         xj = jnp.asarray(xb)
         aj = jnp.asarray(assign)
 
-        t0 = time.perf_counter()
-        if self.use_kernel:
-            preds = serving.serve_voted_kernel(snap.w, snap.count, xj, aj,
-                                               interpret=self.interpret)
-        else:
-            preds = serving.serve_voted(snap.w, snap.count, xj, aj)
-        preds.block_until_ready()
-        dt = time.perf_counter() - t0
+        with telemetry_mod.span("serve_device") as dev:
+            if self.use_kernel:
+                preds = serving.serve_voted_kernel(
+                    snap.w, snap.count, xj, aj, interpret=self.interpret)
+            else:
+                preds = serving.serve_voted(snap.w, snap.count, xj, aj)
+            preds.block_until_ready()
+        dt = dev.seconds
         self.hist.record(dt)
 
         fresh = None

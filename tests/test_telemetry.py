@@ -1,4 +1,5 @@
-"""Telemetry subsystem: bitwise invisibility, stream parity, histograms.
+"""Telemetry subsystem: the always-on span record, bitwise invisibility,
+stream parity, histograms.
 
 The contract under test (docs/CONTRACTS.md + docs/OBSERVABILITY.md):
 telemetry is a **pure read**. Arming a :class:`repro.core.telemetry.
@@ -8,7 +9,9 @@ because the streams are reads of the same protocol, the reference engine
 and the sharded engine must emit bitwise-equal metric streams at a
 matched seed — the metric stream is itself a cross-engine parity surface.
 The message-economy balance invariant (PR 1's run-total identity) must
-hold per cycle from the streams alone."""
+hold per cycle from the streams alone. Every run records its span tree
+(``SimResult.trace``): the spans, their parents and counters, and the
+compiles, and under a profiler session the same spans reach the trace."""
 from __future__ import annotations
 
 import dataclasses
@@ -19,10 +22,10 @@ import pytest
 
 from repro.configs.gossip_linear import (GossipLinearConfig,
                                          with_failure_scenario)
+from repro.core import telemetry
 from repro.core.simulation import message_wire_bytes, run_simulation
 from repro.core.telemetry import (METRIC_STREAMS, SPAN_NAMES, TRACKS,
-                                  LatencyHistogram, Telemetry, best_of,
-                                  maybe_span)
+                                  LatencyHistogram, Telemetry)
 
 
 def toy(n=256, d=8, seed=0):
@@ -204,6 +207,7 @@ def test_histogram_merge_is_exact_bucket_addition():
 
 
 def test_best_of_returns_min_and_result():
+    from benchmarks.common import best_of
     calls = []
     best, secs, result = best_of(lambda: calls.append(0) or len(calls),
                                  repeats=3)
@@ -214,14 +218,27 @@ def test_best_of_returns_min_and_result():
 
 
 def test_maybe_span_unarmed_is_noop():
-    with maybe_span(None, "route_chunk"):
-        pass  # no Telemetry object: nullcontext, nothing recorded
+    """With no Telemetry and no run, a span is still recorded — among the
+    loose spans, with its counters and nesting — and a nested span's
+    parent is the one open when it started."""
+    with telemetry.span("submit", rows=3) as outer:
+        with telemetry.span("serve_batch", queries=3) as inner:
+            inner.counters["padded"] = 1
+    loose = telemetry.loose_spans()
+    assert loose[-2:] == [outer, inner]
+    assert outer.run is None and outer.parent is None
+    assert inner.parent == outer.id
+    assert inner.counters == {"queries": 3, "padded": 1}
+    assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
+    assert outer.track == "serving"
 
 
 def test_span_track_validation():
-    tel = Telemetry()
+    """A span's name fixes its track; a name outside ``SPAN_NAMES`` (the
+    names the benchmark's readers find spans by) is refused."""
     with pytest.raises(ValueError):
-        tel.span("cycle", track="not_a_track")
+        telemetry.span("not_a_span")
+    assert all(track in TRACKS for track, _ in SPAN_NAMES.values())
 
 
 def test_chrome_trace_schema(tmp_path):
@@ -242,10 +259,17 @@ def test_chrome_trace_schema(tmp_path):
     assert thread_names == set(TRACKS)
     spans = [e for e in events if e["ph"] == "X"]
     assert spans and len(spans) == len(tel.spans)
+    ids = {e["args"]["id"] for e in spans}
     for e in spans:
         assert e["name"] in SPAN_NAMES
-        assert e["dur"] >= 0 and e["cat"] in TRACKS
-        assert "compiles" in e["args"]
+        assert e["dur"] >= 0 and e["cat"] == SPAN_NAMES[e["name"]][0]
+        assert e["args"]["parent"] is None or e["args"]["parent"] in ids
+    # compile spans carry the compiled function's name
+    for e in spans:
+        if e["name"] == "compile":
+            assert e["args"]["fun_name"].startswith("jit(")
+    assert payload["otherData"]["compile_total"] == sum(
+        e["name"] == "compile" for e in spans)
     counters = {e["name"] for e in events if e["ph"] == "C"}
     cycle_streams = {n for n, s in METRIC_STREAMS.items()
                      if s.cadence == "cycle"}
@@ -284,8 +308,10 @@ def test_span_names_documented():
     srv.flush()
     emitted = {s.name for s in tel.spans}
     assert emitted <= set(SPAN_NAMES)
-    assert {"cycle", "eval", "route_chunk", "chunk_dispatch",
-            "snapshot", "snapshot_adopt", "serve_batch"} <= emitted
+    assert {"run", "setup", "cycle", "eval", "key_schedule", "stage_draws",
+            "route_chunk", "pack_tables", "upload_tables", "dispatch",
+            "collect_results", "snapshot", "snapshot_adopt", "submit",
+            "serve_batch", "serve_device"} <= emitted
     # the serving histogram is shared into the telemetry object
     assert tel.histograms["serve_batch_latency"].count == len(srv.batches)
 
@@ -323,3 +349,154 @@ def test_multi_run_arming_concatenates_streams():
                        engine="sharded", telemetry=tel, **KW)
     assert tel.stream_array("sent").size == 2 * KW["cycles"]
     assert len(tel.annotations["runs"]) == 2
+
+
+# ------------------------------------------------------- the span record
+
+
+def _served_run(engine="sharded", telemetry_obj=None, cycles=25, **kw):
+    """A run with a GossipServer on its hook (two full batches and a tail
+    at every eval point); returns the result and the final cache rows."""
+    from repro.launch.gossip_serve import GossipServer
+    X, y, Xt, yt = toy()
+    srv = GossipServer(batch_size=16, compare_fresh=False)
+    final = {}
+
+    def hook(cycle, snap):
+        srv.serve_hook(cycle, snap)
+        srv.submit(Xt[:40])
+        srv.flush()
+        final.update(w=np.asarray(snap.w), count=np.asarray(snap.count))
+
+    res = run_simulation(small_cfg(scenario="extreme"), X, y, Xt, yt,
+                         engine=engine, serve_hook=hook,
+                         telemetry=telemetry_obj,
+                         **dict(KW, cycles=cycles, **kw))
+    return res, final
+
+
+def test_unarmed_run_records_the_span_tree():
+    """An unarmed sharded run records the engine's and the server's spans
+    under its root, each under the right parent, with exact counters: the
+    tables' bytes, the draws' bytes, the data's bytes and the router's
+    message counts."""
+    X, y, Xt, yt = toy()
+    res, _ = _served_run(compact_rounds=False)
+    rec = res.trace
+    root = rec.spans[0]
+    assert root.name == "run" and root.parent is None
+    assert root.counters == {"n": 256, "cycles": KW["cycles"]}
+    by_id = {s.id: s for s in rec.spans}
+    parent = lambda s: by_id[s.parent].name
+    for s in rec.spans:
+        assert s.run == rec.id and s.t1_ns is not None
+        assert root.t0_ns <= s.t0_ns <= s.t1_ns <= root.t1_ns
+    want = {"setup": {"run"}, "key_schedule": {"run"},
+            "stage_draws": {"run"}, "route_chunk": {"run"},
+            "pack_tables": {"route_chunk"}, "upload_tables": {"run"},
+            "dispatch": {"run"}, "snapshot": {"run"},
+            "snapshot_adopt": {"snapshot"}, "submit": {"snapshot"},
+            "flush": {"snapshot"}, "serve_batch": {"submit", "flush"},
+            "serve_device": {"serve_batch"}, "collect_results": {"run"}}
+    for name, parents in want.items():
+        assert rec.named(name), name
+        assert {parent(s) for s in rec.named(name)} == parents, name
+    chunks = [10, 10, 5]                   # eval every 10 over 25 cycles
+    for name in ("route_chunk", "upload_tables", "dispatch", "snapshot"):
+        assert len(rec.named(name)) == len(chunks), name
+    assert len(rec.named("serve_batch")) == 3 * len(chunks)
+    # dense tables: (T, K, N) int32 a chunk
+    k = KW["k_rounds"]
+    assert rec.total("upload_tables", "h2d_bytes") == sum(
+        t * k * 256 * 4 for t in chunks)
+    assert rec.total("stage_draws", "d2h_bytes") == 2 * 4 * 256 * 25
+    assert rec.total("setup", "h2d_bytes") == (
+        4 * (X.size + y.size + Xt.size + yt.size) + 4 * 100)
+    assert rec.total("route_chunk", "sends") == res.sent_total
+    assert rec.total("route_chunk", "deliveries") == res.delivered_total
+    assert {s.counters["mode"] for s in rec.named("route_chunk")} == {
+        "dense"}
+    assert [s.counters["queries"] for s in rec.named("serve_batch")] == [
+        16, 16, 8] * len(chunks)
+    assert telemetry.runs()[-1] is rec
+
+
+def test_reference_run_records_its_spans():
+    res, _ = _served_run(engine="reference")
+    rec = res.trace
+    assert rec.engine == "reference"
+    by_id = {s.id: s for s in rec.spans}
+    assert len(rec.named("cycle")) == KW["cycles"]
+    assert len(rec.named("eval")) == len(rec.named("snapshot")) == 3
+    for name in ("setup", "cycle", "eval", "snapshot"):
+        assert {by_id[s.parent].name for s in rec.named(name)} == {"run"}
+
+
+def test_span_record_is_bitwise_invisible():
+    """The spans change nothing: an unarmed run's curves, totals and final
+    cache equal the armed run's bit for bit, and an identical unarmed
+    rerun compiles no new engine signature."""
+    from repro.core.sharded_engine import retrace_counts
+    plain, plain_cache = _served_run()
+    counts = retrace_counts()
+    again, again_cache = _served_run()
+    assert retrace_counts() == counts
+    armed, armed_cache = _served_run(telemetry_obj=Telemetry())
+    for other, cache in ((again, again_cache), (armed, armed_cache)):
+        assert totals(plain) == totals(other)
+        for key in ("w", "count"):
+            assert np.array_equal(plain_cache[key], cache[key])
+
+
+def test_key_schedule_compile_is_its_child():
+    """The compile of the key schedule (an eager scan, traced anew each
+    run) is recorded as a ``compile`` span under ``key_schedule``, with
+    the compiled function's name."""
+    res, _ = _served_run(cycles=13)
+    rec = res.trace
+    ks = rec.named("key_schedule")
+    assert len(ks) == 1
+    under = [s for s in rec.named("compile") if s.parent == ks[0].id]
+    assert under, [s.counters for s in rec.named("compile")]
+    for s in under:
+        assert s.counters["fun_name"].startswith("jit(")
+        assert 0 < s.counters["seconds"] and s.t0_ns <= s.t1_ns
+        assert ks[0].t0_ns <= s.t1_ns <= ks[0].t1_ns
+
+
+def test_profiled_run_reaches_the_trace(tmp_path):
+    """Inside a profiler session the run is marked ``profiled``, stays the
+    last profiled run after later runs, and its spans are in the
+    ``.xplane.pb`` as ``gossip/<name>`` annotations."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        res, _ = _served_run(cycles=10)
+    later, _ = _served_run(cycles=10)
+    assert res.trace.profiled and not later.trace.profiled
+    assert telemetry.last_profiled() is res.trace
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path[0]).planes:
+        for line in plane.lines:
+            names.update(e.name for e in line.events
+                         if e.name.startswith(telemetry.ANNOTATION_PREFIX))
+    assert {"gossip/run", "gossip/setup", "gossip/route_chunk",
+            "gossip/dispatch", "gossip/snapshot_adopt",
+            "gossip/serve_device"} <= names
+
+
+def test_run_ring_is_bounded_and_keeps_the_profiled_run(tmp_path):
+    import jax
+
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.run("reference", n=1, cycles=1) as profiled:
+            pass
+    for _ in range(telemetry.RUNS_KEPT + 3):
+        with telemetry.run("reference", n=1, cycles=1):
+            pass
+    assert len(telemetry.runs()) == telemetry.RUNS_KEPT
+    assert profiled not in telemetry.runs()
+    assert telemetry.last_profiled() is profiled

@@ -36,19 +36,10 @@ Also guards every file's ``parity_bitwise`` probe: any wire codec whose
 cross-engine curves stopped being bitwise-identical fails regardless of
 speed — for the wire bench that covers the full codec registry, and for
 the serving bench the snapshot engine-parity / Pallas-kernel-vs-jnp /
-serving-never-perturbs probes, and for the telemetry-overhead bench the
-armed-invisibility probes. Rows
+serving-never-perturbs probes. Rows
 carrying a ``retraces`` field (compiles triggered per bench row) are
 diffed informationally — the hard compile-count gate is
 ``tools/lint/retrace_guard.py``.
-
-Files whose ``derived`` block carries ``telemetry_overhead_ratio``
-(BENCH_telemetry_overhead.json) get one extra rule: the current armed/
-unarmed ratio must not exceed ``OVERHEAD_SLACK`` × the committed
-baseline's ratio — so telemetry that silently got more expensive fails
-even while both arms individually clear the rate tolerance. (The ≤ 5%
-absolute acceptance criterion lives in the committed full-run baseline
-itself, recorded as ``derived.overhead_within_ceiling``.)
 """
 from __future__ import annotations
 
@@ -61,10 +52,6 @@ from pathlib import Path
 # rate comparisons need the run to be throughput-dominated, not
 # overhead-dominated: below ~10^6 node-cycles a run is mostly fixed cost
 MIN_NODE_CYCLES = 1_000_000
-
-# the armed/unarmed telemetry ratio may drift this much vs the committed
-# baseline before the smoke gate fails (container noise on a ~1.0 ratio)
-OVERHEAD_SLACK = 1.10
 
 
 def row_key(row: dict):
@@ -105,22 +92,6 @@ def check_pair(base_fp: Path, cur_fp: Path, tolerance: float,
         print(f"check_bench_regression: unparsable baseline at {base_fp} — "
               "treating as missing, skipping rate comparison")
         return
-
-    # telemetry-overhead rule: the armed/unarmed ratio must not creep up
-    # relative to the committed baseline (a drift check on a ~1.0 number,
-    # independent of how fast the container happens to be today)
-    cratio = cur.get("derived", {}).get("telemetry_overhead_ratio")
-    bratio = base.get("derived", {}).get("telemetry_overhead_ratio")
-    if cratio is not None and bratio is not None:
-        verdict = "ok"
-        if cratio > OVERHEAD_SLACK * bratio:
-            verdict = "REGRESSED"
-            failures.append(
-                f"  [{label}] telemetry_overhead_ratio: {cratio:.3f}x vs "
-                f"baseline {bratio:.3f}x (exceeds {OVERHEAD_SLACK}x slack "
-                "— armed telemetry got more expensive)")
-        print(f"check_bench_regression: [{label}] telemetry_overhead_ratio "
-              f"{cratio:.3f}x vs baseline {bratio:.3f}x ({verdict})")
 
     base_rows = {row_key(r): r for r in base.get("rows", [])}
     cur_rows = {row_key(r): r for r in cur.get("rows", [])}

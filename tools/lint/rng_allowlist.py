@@ -23,7 +23,7 @@ RNG_ALLOWED: Dict[Tuple[str, str], FrozenSet[str]] = {
         frozenset({"split", "randint", "bernoulli"}),
     # reference driver key chain: key, sub = split(key) once per cycle;
     # key_schedule replays it bitwise on device
-    ("core/simulation.py", "run_simulation"): frozenset({"split"}),
+    ("core/simulation.py", "_run_reference"): frozenset({"split"}),
     # sharded engine: device-side replay of the driver key chain
     ("core/sharded_engine.py", "key_schedule.body"): frozenset({"split"}),
     # sharded engine control plane: scanned replica of cycle_core's

@@ -5,23 +5,20 @@
 #   tools/run_tests.sh --fast        inner-loop subset (skips the slow
 #                                    model-zoo and perf-profile suites)
 #   tools/run_tests.sh --bench-smoke fast subset, then the population-scaling,
-#                                    wire-quantization, robustness, serving
-#                                    and telemetry-overhead benchmarks in
-#                                    --quick mode (refreshing
+#                                    wire-quantization, robustness and
+#                                    serving benchmarks in --quick mode
+#                                    (refreshing
 #                                    BENCH_population_scaling.json /
 #                                    BENCH_wire_quantization.json /
 #                                    BENCH_robustness.json /
-#                                    BENCH_serving.json /
-#                                    BENCH_telemetry_overhead.json), then
+#                                    BENCH_serving.json), then
 #                                    tools/check_bench_regression.py compares
-#                                    the fresh rates of ALL five benches
+#                                    the fresh rates of ALL four benches
 #                                    against the committed BENCH_*.json
 #                                    baselines — an engine perf regression
 #                                    (or a broken cross-engine wire-codec /
-#                                    fault-model / serving-snapshot /
-#                                    telemetry-invisibility parity probe, or
-#                                    an armed-telemetry overhead ratio past
-#                                    1.10x the committed one) fails loudly
+#                                    fault-model / serving-snapshot parity
+#                                    probe) fails loudly
 #
 # Every mode first runs tools/check_docs.py (a doc referencing a removed
 # symbol fails tier 1) and tools/lint/run.py (repro-lint: the parity
@@ -65,25 +62,22 @@ if [[ "${1:-}" == "--bench-smoke" ]]; then
     wire_baseline="$(mktemp /tmp/wire_baseline.XXXXXX.json)"
     robust_baseline="$(mktemp /tmp/robust_baseline.XXXXXX.json)"
     serving_baseline="$(mktemp /tmp/serving_baseline.XXXXXX.json)"
-    telem_baseline="$(mktemp /tmp/telem_baseline.XXXXXX.json)"
-    trap 'rm -f "$baseline" "$wire_baseline" "$robust_baseline" "$serving_baseline" "$telem_baseline"' EXIT
+    trap 'rm -f "$baseline" "$wire_baseline" "$robust_baseline" "$serving_baseline"' EXIT
     # mktemp pre-creates an EMPTY file: remove it so a tree without a
     # committed baseline takes the checker's "no baseline" skip path
     # instead of failing to parse zero bytes of JSON
-    rm -f "$baseline" "$wire_baseline" "$robust_baseline" "$serving_baseline" "$telem_baseline"
+    rm -f "$baseline" "$wire_baseline" "$robust_baseline" "$serving_baseline"
     cp BENCH_population_scaling.json "$baseline" 2>/dev/null || true
     cp BENCH_wire_quantization.json "$wire_baseline" 2>/dev/null || true
     cp BENCH_robustness.json "$robust_baseline" 2>/dev/null || true
     cp BENCH_serving.json "$serving_baseline" 2>/dev/null || true
-    cp BENCH_telemetry_overhead.json "$telem_baseline" 2>/dev/null || true
     python -m benchmarks.run --quick \
-        --only population_scaling,wire_quantization,robustness,serving,telemetry_overhead
+        --only population_scaling,wire_quantization,robustness,serving
     python tools/check_bench_regression.py \
         --pair "$baseline" BENCH_population_scaling.json \
         --pair "$wire_baseline" BENCH_wire_quantization.json \
         --pair "$robust_baseline" BENCH_robustness.json \
-        --pair "$serving_baseline" BENCH_serving.json \
-        --pair "$telem_baseline" BENCH_telemetry_overhead.json
+        --pair "$serving_baseline" BENCH_serving.json
     exit 0
 fi
 exec python -m pytest -x -q "$@"

@@ -6,8 +6,9 @@ trace exported on one machine can be summarized anywhere:
 
     PYTHONPATH=src python tools/trace_report.py results/trace.json
 
-Prints the per-phase wall-clock table (time, %, span counts, jit
-compiles), the compile events, and the per-cycle stream summary
+Prints the per-phase wall-clock table (time, %, span counts), the
+compile spans (``fun_name``, seconds, the span they ran under), and the
+per-cycle stream summary
 (messages + wire bytes per cycle, message-economy balance check) that
 ``otherData.streams`` carries. The same numbers an armed run prints live
 via ``Telemetry.phase_report()`` — this is the offline twin for committed
@@ -29,8 +30,7 @@ def summarize(payload: dict) -> str:
 
     label = other.get("label") or "(unlabeled)"
     spans = [e for e in events if e.get("ph") == "X"]
-    compiles = [e for e in events if e.get("ph") == "i"
-                and e.get("cat") == "compile"]
+    compiles = [e for e in spans if e["name"] == "compile"]
     lines.append(f"trace: {label} — {len(spans)} spans, "
                  f"{other.get('compile_total', 0)} jit compiles")
 
@@ -41,20 +41,22 @@ def summarize(payload: dict) -> str:
         wall = (t1 - t0) / 1e6
         per: dict = {}
         for e in spans:
-            name = e["name"]
-            secs, cnt, comp = per.get(name, (0.0, 0, 0))
-            per[name] = (secs + e["dur"] / 1e6, cnt + 1,
-                         comp + int(e.get("args", {}).get("compiles", 0)))
+            secs, cnt = per.get(e["name"], (0.0, 0))
+            per[e["name"]] = (secs + e["dur"] / 1e6, cnt + 1)
         lines.append(f"phases ({wall:.3f}s spanned wall clock):")
-        for name, (secs, cnt, comp) in sorted(per.items(),
-                                              key=lambda kv: -kv[1][0]):
+        for name, (secs, cnt) in sorted(per.items(),
+                                        key=lambda kv: -kv[1][0]):
             pct = 100.0 * secs / wall if wall > 0 else 0.0
             lines.append(f"  {name:<16} {secs:>9.3f}s {pct:>5.1f}%  "
-                         f"x{cnt:<5d} compiles={comp}")
+                         f"x{cnt}")
     if compiles:
-        lines.append(f"compile events: {len(compiles)}")
+        names = {e.get("args", {}).get("id"): e["name"] for e in spans}
+        lines.append(f"compile spans: {len(compiles)}")
         for e in compiles:
-            lines.append(f"  {e['ts'] / 1e6:>9.3f}s  {e['name']}")
+            a = e.get("args", {})
+            lines.append(f"  {e['ts'] / 1e6:>9.3f}s  {a.get('fun_name', '?')}"
+                         f" {e['dur'] / 1e3:.1f}ms under "
+                         f"{names.get(a.get('parent'), '-')}")
 
     # stream summary + the continuously-emitted balance invariant
     streams = other.get("streams", {})
