@@ -14,7 +14,8 @@ network:
   calls as the reference engine (bitwise-identical), pulls the integer
   tables to the host, and resolves the K winner rounds with vectorized
   numpy fancy-index assignments (no XLA scatters); routing for the next
-  chunk overlaps the in-flight device scan. The message economy stats
+  chunk runs right after this chunk is dispatched, so it overlaps the
+  in-flight device scan. The message economy stats
   (sent/delivered/lost/overflow) fall out of the same pass.
 * **data plane in one ``lax.scan``** — all cycles between two eval points
   run as ONE XLA program over the precomputed routing tables: gather the
@@ -1117,13 +1118,19 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     read of the live cache lanes: bitwise identical to the reference
     engine's snapshot at the same cycle, and provably non-perturbing (the
     scan never observes the hook). The hook must consume the snapshot
-    before the next chunk runs — the chunk fn donates its carry.
+    before the next chunk runs — the chunk fn donates its carry. The hook
+    of chunk i is called after the host has routed chunk i+1 (routing
+    runs while chunk i is on the device) and before chunk i+1 is
+    dispatched.
 
     Host spans (``repro.core.telemetry.span``), always recorded: ``setup``
     (``h2d_bytes``), ``key_schedule``, ``stage_draws`` (``d2h_bytes``),
-    per chunk ``route_chunk`` (``sends``, ``deliveries``, ``mode``) with
-    its child ``pack_tables``, ``upload_tables`` (``h2d_bytes``),
-    ``dispatch`` and ``snapshot``, and the final ``collect_results``.
+    then ``route_chunk`` of chunk 0 and, per chunk i, ``upload_tables``
+    (``h2d_bytes``), ``dispatch``, ``route_chunk`` of chunk i+1 and
+    ``snapshot``, and the final ``collect_results``. Each ``route_chunk``
+    (``sends``, ``deliveries``, ``mode``) has its child ``pack_tables``;
+    those of chunks 1… also carry ``chunk_in_flight``: 1 when chunk i was
+    still on the device when routing ended, else 0.
 
     ``telemetry``: optional ``repro.core.telemetry.Telemetry`` — same
     pure-read discipline as ``serve_hook``. Armed runs emit the registered
@@ -1264,10 +1271,15 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
         return np.asarray(dsts), np.asarray(arrivals)
 
     # With all integer draws staged upfront (bounded: 8 bytes/node-cycle),
-    # chunk i+1's host routing overlaps chunk i's device scan — the scan is
-    # dispatched asynchronously and only the eval results are fetched, once,
-    # after the last chunk. Each staged entry is released right after it is
-    # routed, so host memory stays bounded by ~one chunk of draw tables.
+    # chunk i+1's host routing overlaps chunk i's device scan: the scan is
+    # dispatched asynchronously, chunk i+1 is routed right after, before
+    # anything waits for chunk i (the snapshot build, the armed EF read),
+    # and the eval results are fetched once, after the last chunk. Each
+    # staged entry is released right after it is routed, so host memory
+    # stays bounded by ~one chunk of draw tables. Without the staging
+    # (larger runs) routing does not overlap: each chunk's draws run on
+    # the device behind the chunk before it, and copying them to the host
+    # waits for that chunk.
     prefetch = cycles * n <= 250_000_000
     if prefetch:
         with telemetry_mod.span("stage_draws", chunks=len(bounds)) as sp:
@@ -1376,12 +1388,19 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             tables = (*tables, an >= 0)
         return mode, tables, stats
 
-    def routed(i):
-        """``route(i)`` in its span, with the chunk's message counts."""
+    def routed(i, ahead=None):
+        """``route(i)`` in its span, with the chunk's message counts.
+        ``ahead``: a leaf of the carry returned by the chunk dispatched
+        before this routing; the span's ``chunk_in_flight`` then says,
+        without waiting, whether that chunk was still on the device when
+        routing ended (1: routing was hidden under it; 0: the device
+        waited for the host)."""
         with telemetry_mod.span("route_chunk", chunk=i) as sp:
             out = route(i)
             sp.counters.update(sends=out[2]["sent"],
                                deliveries=out[2]["delivered"], mode=out[0])
+            if ahead is not None:
+                sp.counters["chunk_in_flight"] = int(not ahead.is_ready())
         return out
 
     msg_bytes = message_wire_bytes(d, cfg.wire_dtype)
@@ -1401,6 +1420,13 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
         # no reference past the dispatch: the tables are freed as soon as
         # the chunk is done with them, not when the next chunk's arrive
         del dev_tables
+        if i + 1 < len(pts):
+            # route chunk i+1 while chunk i runs: before the snapshot build
+            # and the EF read below wait for chunk i. Routing moves only the router's own state and the staged
+            # draws; the hook and the armed reads see chunk i's carry and
+            # stats. The tables go up at the top of the next iteration, so
+            # that one chunk's tables at a time are on the device.
+            pending = routed(i + 1, ahead=carry[0])
         if serve_hook is not None:
             # pure read of the fresh carry, dispatched before the next
             # chunk donates it; the scan never observes the hook, so the
@@ -1413,8 +1439,6 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             # before the next chunk donates the carry (a no-op float for
             # non-EF codecs — carry[12] is the empty (0, 0) lane)
             tel.emit("ef_residual_rms", ef_residual_norm(carry[12]))
-        if i + 1 < len(pts):
-            pending = routed(i + 1)   # overlaps the in-flight device scan
         res.sent_total += stats["sent"]
         res.delivered_total += stats["delivered"]
         res.lost_total += stats["lost"]

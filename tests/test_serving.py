@@ -11,6 +11,8 @@ score-sum vote. (3) Serving never perturbs the protocol: a run with a
 serve hook produces bitwise the curves of a run without one, snapshots are
 bitwise identical across engines, and served answers are reproducible for
 a fixed seed."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -193,24 +195,59 @@ def _sim_setup(seed=0):
 
 
 def _run(engine, serve_hook=None, scenario=None):
+    """``engine`` is ``"reference"``, ``"sharded"`` (the packing chosen per
+    chunk) or ``"sharded-<packing>"`` (one packing forced for every
+    chunk)."""
     cfg, X, y, Xt, yt = _sim_setup()
     if scenario is not None:
         from repro.configs.gossip_linear import with_failure_scenario
         cfg = with_failure_scenario(cfg, scenario)
+    engine, _, packing = engine.partition("-")
+    kw = dict(compact_mode=packing) if packing else {}
     return run_simulation(cfg, X, y, Xt, yt, cycles=CYCLES,
                           eval_every=EVAL_EVERY, seed=3, engine=engine,
-                          serve_hook=serve_hook)
+                          serve_hook=serve_hook, **kw)
 
 
-@pytest.mark.parametrize("engine", ["reference", "sharded"])
+def _result_fields(res):
+    """Every field of a SimResult but its span record."""
+    return {f.name: getattr(res, f.name) for f in dataclasses.fields(res)
+            if f.name != "trace"}
+
+
+@pytest.fixture(scope="module")
+def reference_snapshots():
+    """``get(scenario)``: the reference engine's snapshots at every eval
+    point, on the host, computed once a scenario."""
+    by_scenario = {}
+
+    def get(scenario):
+        if scenario not in by_scenario:
+            snaps = by_scenario[scenario] = {}
+            _run("reference", scenario=scenario,
+                 serve_hook=lambda cycle, snap: snaps.__setitem__(
+                     cycle, jax.tree.map(np.array, snap)))
+        return by_scenario[scenario]
+    return get
+
+
+@pytest.mark.parametrize("engine", ["reference", "sharded", "sharded-dense",
+                                    "sharded-compact", "sharded-compact_all"])
 @pytest.mark.parametrize("scenario", [None, "extreme"])
-def test_serving_does_not_perturb_the_run(engine, scenario):
+def test_serving_does_not_perturb_the_run(engine, scenario,
+                                          reference_snapshots):
     """A hooked run (snapshots taken AND queries served at every eval
-    point) produces bitwise the same error curves as an unhooked run."""
+    point) produces bitwise the same SimResult as an unhooked run, under
+    every packing of the sharded engine, and its snapshots are bitwise the
+    reference engine's at every eval point. The sharded engine routes the
+    next chunk before it calls the hook, so this also pins that the hook
+    sees nothing of that routing."""
     srv = GossipServer(batch_size=16, seed=1)
     qX = np.asarray(_sim_setup()[3][:24], np.float32)
+    snaps = {}
 
     def hook(cycle, snap):
+        snaps[cycle] = jax.tree.map(np.array, snap)
         srv.serve_hook(cycle, snap)
         srv.submit(qX)
 
@@ -222,6 +259,16 @@ def test_serving_does_not_perturb_the_run(engine, scenario):
                            np.asarray(clean.err_fresh))
     npt.assert_array_equal(np.asarray(hooked.err_voted),
                            np.asarray(clean.err_voted))
+    assert _result_fields(hooked) == _result_fields(clean)
+    if "-" in engine:
+        assert hooked.compaction["chunk_modes"][engine.split("-")[1]] == len(
+            hooked.cycles)
+    ref = reference_snapshots(scenario)
+    assert sorted(snaps) == sorted(ref) == hooked.cycles
+    for cyc in ref:
+        for field, a, b in zip(serving.QuerySnapshot._fields, ref[cyc],
+                               snaps[cyc]):
+            npt.assert_array_equal(a, b, err_msg=f"cycle {cyc}: {field}")
 
 
 def test_snapshot_parity_reference_vs_sharded():

@@ -421,6 +421,30 @@ def test_unarmed_run_records_the_span_tree():
     assert telemetry.runs()[-1] is rec
 
 
+@pytest.mark.parametrize("compact_mode", ["dense", "compact_all"])
+def test_next_chunk_is_routed_before_the_snapshot(compact_mode):
+    """The sharded engine routes chunk i+1 right after it dispatches chunk
+    i, before it builds chunk i's snapshot and calls the hook; each routing
+    with a chunk dispatched ahead of it says whether that chunk was still
+    on the device when routing ended (``chunk_in_flight``), the first
+    routing says nothing."""
+    res, _ = _served_run(compact_mode=compact_mode)
+    rec = res.trace
+    route = sorted(rec.named("route_chunk"), key=lambda s: s.counters["chunk"])
+    dispatch = sorted(rec.named("dispatch"), key=lambda s: s.counters["chunk"])
+    snap = sorted(rec.named("snapshot"), key=lambda s: s.counters["cycle"])
+    assert len(route) == len(dispatch) == len(snap) == 3
+    assert route[0].t1_ns <= dispatch[0].t0_ns
+    for i in range(len(snap) - 1):
+        assert dispatch[i].t1_ns <= route[i + 1].t0_ns
+        assert route[i + 1].t1_ns <= snap[i].t0_ns
+        assert snap[i].t1_ns <= dispatch[i + 1].t0_ns
+    assert "chunk_in_flight" not in route[0].counters
+    for s in route[1:]:
+        assert s.counters["chunk_in_flight"] in (0, 1)
+    assert {s.counters["mode"] for s in route} == {compact_mode}
+
+
 def test_reference_run_records_its_spans():
     res, _ = _served_run(engine="reference")
     rec = res.trace
