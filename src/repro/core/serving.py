@@ -73,10 +73,13 @@ def take_snapshot(state) -> QuerySnapshot:
 
 def snapshot_from_carry(carry) -> QuerySnapshot:
     """Snapshot from the sharded engine's scan carry (the 14-lane tuple:
-    cache lanes 4–7, clock lane 13) — bitwise identical to
-    :func:`take_snapshot` of the reference engine at the same cycle."""
-    cache = ModelCache(carry[4], carry[5], carry[6], carry[7])
-    return _snapshot(cache, carry[13])
+    freshest model lanes 2–3, cache lanes 4–7, clock lane 13) — bitwise
+    identical to :func:`take_snapshot` of the reference engine at the same
+    cycle. The engine keeps each node's freshest model in lanes 2–3 (what
+    it sends), equal to ``cache.freshest`` of lanes 4–7, so the snapshot
+    only refers to the carry and allocates nothing on the device."""
+    return QuerySnapshot(carry[4], carry[5], carry[7], carry[2], carry[3],
+                         carry[13])
 
 
 def assign_queries(n_queries: int, n_nodes: int, *,

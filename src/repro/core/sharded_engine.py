@@ -488,20 +488,29 @@ def _pallas_apply(lam: float, interpret: bool, wire, defense: str = "none"):
     decodes in VMEM (affine dequant, int4 nibble unpack, base-3 ternary
     unpack), so HBM message traffic is paid at wire precision. The
     ``defense`` screen runs in-kernel between the decode and the merge
-    (same round-chain placement as the jnp paths)."""
-    from repro.kernels.gossip_cycle import fused_receive_apply
+    (same round-chain placement as the jnp paths).
+
+    Where a whole node block does not fit VMEM (``receive_d_tile`` < d,
+    computed from the shapes), the d-tiled kernel runs instead; it takes
+    the f32 wire, the "mu" variant and no defense, and refuses the rest."""
+    from repro.kernels.gossip_cycle import (fused_receive_apply,
+                                            fused_receive_apply_dtiled,
+                                            receive_d_tile)
 
     def apply_fn(last_w, last_t, fresh_w, fresh_t, cache, msg_w, msg_t,
                  valid, X, y, *, variant, update, msg_scale=None,
                  msg_zp=None):
         del update  # the kernel implements the Pegasos step itself
-        lw, lt, fw, ft, cw, ct, ptr, cnt, gated, clipped = \
-            fused_receive_apply(
-                last_w, last_t, fresh_w, fresh_t, cache.w, cache.t,
-                cache.ptr, cache.count, msg_w, msg_t,
-                valid.astype(jnp.int32), X, y, msg_scale=msg_scale,
-                msg_zp=msg_zp, wire=wire, variant=variant, lam=lam,
-                interpret=interpret, defense=defense)
+        (n, d), (k, c) = last_w.shape, (msg_w.shape[0], cache.w.shape[1])
+        tile = receive_d_tile(n, d, c, k, wire)
+        kernel, kw = fused_receive_apply, {}
+        if tile != d:
+            kernel, kw = fused_receive_apply_dtiled, dict(d_tile=tile)
+        lw, lt, fw, ft, cw, ct, ptr, cnt, gated, clipped = kernel(
+            last_w, last_t, fresh_w, fresh_t, cache.w, cache.t, cache.ptr,
+            cache.count, msg_w, msg_t, valid.astype(jnp.int32), X, y,
+            msg_scale=msg_scale, msg_zp=msg_zp, wire=wire, variant=variant,
+            lam=lam, interpret=interpret, defense=defense, **kw)
         return lw, lt, fw, ft, ModelCache(cw, ct, ptr, cnt), gated, clipped
 
     return apply_fn
@@ -1121,13 +1130,19 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     before the next chunk runs — the chunk fn donates its carry. The hook
     of chunk i is called after the host has routed chunk i+1 (routing
     runs while chunk i is on the device) and before chunk i+1 is
-    dispatched.
+    dispatched; the hook of the last chunk, once that chunk has finished
+    and the lanes the snapshot does not read (last models, payload buffer)
+    have been freed.
 
     Host spans (``repro.core.telemetry.span``), always recorded: ``setup``
-    (``h2d_bytes``), ``key_schedule``, ``stage_draws`` (``d2h_bytes``),
-    then ``route_chunk`` of chunk 0 and, per chunk i, ``upload_tables``
-    (``h2d_bytes``), ``dispatch``, ``route_chunk`` of chunk i+1 and
-    ``snapshot``, and the final ``collect_results``. Each ``route_chunk``
+    (``h2d_bytes``; with Pallas ``receive_d_tile``, the coefficients a grid
+    step of the receive kernel holds, d for the whole-d kernel),
+    ``key_schedule``, ``stage_draws`` (``d2h_bytes``), then ``route_chunk``
+    of chunk 0 and, per chunk i, ``upload_tables`` (``h2d_bytes``),
+    ``dispatch`` (with Pallas ``receive_bytes``: the HBM bytes the chunk's
+    receive kernel calls move, as their BlockSpecs move them),
+    ``route_chunk`` of chunk i+1 and ``snapshot``, and the final
+    ``collect_results``. Each ``route_chunk``
     (``sends``, ``deliveries``, ``mode``) has its child ``pack_tables``;
     those of chunks 1… also carry ``chunk_in_flight``: 1 when chunk i was
     still on the device when routing ended, else 0.
@@ -1143,13 +1158,6 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
     n, d = X.shape[0], X.shape[-1]
     D = max(cfg.delay_max_cycles, 1)
     codec = get_codec(cfg.wire_dtype)
-    with telemetry_mod.span("setup") as sp:
-        setup = sim_setup(cfg, X, y, X_test, y_test, cycles=cycles,
-                          seed=seed, eval_nodes=eval_nodes)
-        sp.counters["h2d_bytes"] = setup_h2d_bytes((X, y, X_test, y_test),
-                                                   setup)
-    online_mat, eval_idx, X, y, X_test, y_test = setup
-
     if use_pallas is None:
         use_pallas = (jax.default_backend() == "tpu"
                       and cfg.learner == "pegasos")
@@ -1172,6 +1180,24 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             shards = axis_size
         else:
             mesh = axis = None
+
+    recv_tile = recv_bytes = None
+    if use_pallas:
+        # the receive kernel each shard runs, and the HBM bytes one call of
+        # it moves (every shard calls it once a cycle)
+        from repro.kernels.gossip_cycle import (receive_call_bytes,
+                                                receive_d_tile)
+        shape = (n // shards, d, cfg.cache_size, k_rounds, cfg.wire_dtype)
+        recv_tile = receive_d_tile(*shape)
+        recv_bytes = shards * receive_call_bytes(*shape, recv_tile)
+    with telemetry_mod.span("setup") as sp:
+        setup = sim_setup(cfg, X, y, X_test, y_test, cycles=cycles,
+                          seed=seed, eval_nodes=eval_nodes)
+        sp.counters["h2d_bytes"] = setup_h2d_bytes((X, y, X_test, y_test),
+                                                   setup)
+        if recv_tile is not None:
+            sp.counters["receive_d_tile"] = recv_tile
+    online_mat, eval_idx, X, y, X_test, y_test = setup
 
     if compact_rounds is None:
         compact_rounds = not use_pallas
@@ -1413,7 +1439,9 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
         with telemetry_mod.span("upload_tables", chunk=i) as sp:
             dev_tables = tuple(jnp.asarray(a) for a in tables)
             sp.counters["h2d_bytes"] = sum(int(a.nbytes) for a in tables)
-        with telemetry_mod.span("dispatch", chunk=i, cycles=hi - lo):
+        recv = ({} if recv_bytes is None
+                else {"receive_bytes": (hi - lo) * recv_bytes})
+        with telemetry_mod.span("dispatch", chunk=i, cycles=hi - lo, **recv):
             carry, (errs, fstats) = get_chunk_fn(mode)(
                 carry, dev_tables, keydata[lo:hi], X, y, X_test, y_test,
                 eval_idx, byz)
@@ -1433,6 +1461,18 @@ def run_sharded_simulation(cfg: GossipLinearConfig, X, y, X_test, y_test, *,
             # run is bitwise identical with or without serving
             from repro.core import serving
             with telemetry_mod.span("snapshot", cycle=p):
+                if i + 1 == len(pts):
+                    # the last chunk has no successor to overlap: let it
+                    # finish, then free the lanes that neither the
+                    # snapshot (freshest 2-3, cache 4-7, clock 13) nor the
+                    # result (EF residual 12) reads, so that what the hook
+                    # allocates lands far below the chunk's own peak even
+                    # while the chunk's temporaries are being released (on
+                    # the chip that race moved the run's peak memory by
+                    # the size of the hook's allocation)
+                    jax.block_until_ready(carry)
+                    for lane in (0, 1, 8, 9, 10, 11):
+                        carry[lane].delete()
                 serve_hook(p, serving.snapshot_from_carry(carry))
         if armed:
             # the one eager armed read: the EF-residual RMS must be taken

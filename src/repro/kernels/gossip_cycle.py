@@ -21,6 +21,13 @@ f16 scale), so HBM message traffic is paid at wire precision — half a byte
 is ``repro.core.simulation.apply_receives``; parity is tested in interpret
 mode on CPU (tests/test_sharded_engine.py, tests/test_wire_codec.py).
 
+Where a whole node block does not fit VMEM (the paper's Reuters width,
+d=9947), ``fused_receive_apply_dtiled`` does the same receive with the
+coefficient axis tiled: a first pass sums each round's Pegasos margin over
+the d tiles, a second applies merge, step and cache write tile by tile.
+``receive_d_tile`` is the rule that picks between the two from the shapes
+(tests/test_receive_dtiled.py).
+
 This module also holds the send-side counterpart, ``quantize_send``: the
 per-message encode of any quantized wire codec
 (``repro.core.wire_codec``) as one fused pass per node block — affine int8
@@ -34,11 +41,14 @@ same pass (tests/test_send_kernel.py).
 from __future__ import annotations
 
 import functools
+import itertools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # The threefry-2x32 cipher, the counter-at-position uniform it feeds and
 # the wire decode/encode constants live in repro.core.wire_codec (shared
@@ -59,8 +69,8 @@ from jax.experimental import pallas as pl
 # — (d, N), (C, d, N), (K, P, N), per-node scalars as (1, N) — which are
 # bitcasts of the engine's state, not copies. A grid step owns ``blk``
 # nodes on the lane axis; every other block dimension is the array's full
-# dimension, so nothing is padded (the tiling rule accepts a block
-# dimension equal to the array's). A population that is not a multiple of
+# dimension (the d-tiled kernel's coefficient axis aside), so nothing is
+# padded (the tiling rule accepts a block dimension equal to the array's). A population that is not a multiple of
 # ``blk`` leaves a ragged last block: its out-of-range lanes compute
 # garbage that Pallas never writes back, and every operation is per node,
 # so no garbage reaches a real node.
@@ -267,6 +277,149 @@ def _wire_mode(wire, msg_scale, msg_zp) -> str:
     return "float"
 
 
+def _receive_layout(n: int, d: int, c: int, k: int, p: int, msg_dtype,
+                    n_meta: int, d_tile: int | None = None):
+    """Grid, and ``(BlockSpec, array)`` of each operand and each output of a
+    receive kernel in call order, the arrays as node-minor
+    ``ShapeDtypeStruct``s (f32 models). ``d_tile=None`` is the whole-d
+    kernel: one grid step a node block. Otherwise the d-tiled kernel, whose
+    grid is (node block, pass, d tile): the operands both passes read
+    follow the tile; those only the second pass reads, and the tiled
+    outputs, stay on tile 0 through the first pass, so the pipeline moves
+    them once; the per-node rows stay put for the whole node block."""
+    blk = _node_block(n)
+    f32, i32 = jnp.float32, jnp.int32
+    arr = jax.ShapeDtypeStruct
+    if d_tile is None:
+        grid = (pl.cdiv(n, blk),)
+        node = functools.partial(_lanes, blk)
+        both = second = lambda *lead: _lanes(blk, *lead, d)
+        msg = _lanes(blk, k, p)
+    else:
+        grid = (pl.cdiv(n, blk), 2, pl.cdiv(d, d_tile))
+
+        def spec(lead, tiled, every_pass=True):
+            z = (0,) * len(lead)
+            if not tiled:
+                return pl.BlockSpec((*lead, blk), lambda i, q, j: (*z, i))
+            return pl.BlockSpec(
+                (*lead, d_tile, blk),
+                lambda i, q, j: (*z, j if every_pass else j * q, i))
+        node = lambda *lead: spec(lead, False)
+        both = lambda *lead: spec(lead, True)
+        second = lambda *lead: spec(lead, True, every_pass=False)
+        msg = both(k)
+    ins = [(msg, arr((k, p, n), msg_dtype)),
+           (node(k), arr((k, n), i32)),                    # msg_t
+           *[(node(k), arr((k, n), f32))] * n_meta,        # scale, zp
+           (node(k), arr((k, n), i32)),                    # valid
+           (both(), arr((d, n), f32)),                     # x
+           (node(1), arr((1, n), f32)),                    # y
+           (both(), arr((d, n), f32)),                     # last_w
+           (node(1), arr((1, n), i32)),                    # last_t
+           (second(), arr((d, n), f32)),                   # fresh_w
+           (node(1), arr((1, n), i32)),                    # fresh_t
+           (second(c), arr((c, d, n), f32)),               # cache_w
+           (node(c), arr((c, n), i32)),                    # cache_t
+           (node(1), arr((1, n), i32)),                    # ptr
+           (node(1), arr((1, n), i32))]                    # count
+    # the state from last_w on comes back in place, then gated, clipped;
+    # the tiled outputs are written in the second pass only
+    outs = [(second(), arr((d, n), f32)), ins[n_meta + 6],
+            (second(), arr((d, n), f32)), ins[n_meta + 8],
+            (second(c), arr((c, d, n), f32)), *ins[n_meta + 10:],
+            *[(node(1), arr((1, n), i32))] * 2]
+    return grid, ins, outs
+
+
+def _vmem_bytes(shape, itemsize: int) -> int:
+    """VMEM a block takes: the second-minor dimension padded to the
+    sublane tile (8 rows of 32-bit words), the minor one to 128 lanes."""
+    *lead, r, cols = shape
+    sub = 8 * 4 // itemsize
+    return (math.prod(lead) * -(-r // sub) * sub * -(-cols // 128) * 128
+            * itemsize)
+
+
+def _window_bytes(layout) -> int:
+    """VMEM of a kernel's block windows, each double-buffered."""
+    _, ins, outs = layout
+    return 2 * sum(_vmem_bytes(s.block_shape, a.dtype.itemsize)
+                   for s, a in ins + outs)
+
+
+def _moved_bytes(layout) -> int:
+    """HBM bytes the kernel's pipeline moves over its grid: a block is
+    read (operand) or written (output) each time its block index differs
+    from the previous grid step's, clipped to the array's bounds."""
+    grid, ins, outs = layout
+    steps = list(itertools.product(*map(range, grid)))
+    total = 0
+    for spec, a in ins + outs:
+        prev = None
+        for ids in steps:
+            at = spec.index_map(*ids)
+            if at != prev:
+                total += a.dtype.itemsize * math.prod(
+                    min(b, size - i * b)
+                    for b, i, size in zip(spec.block_shape, at, a.shape))
+                prev = at
+    return total
+
+
+# Mosaic grants a kernel 16 MiB of scoped VMEM on v5e. The receive kernel's
+# double-buffered block windows may take WINDOW_BUDGET of it; the rest holds
+# the body's intermediates. Compiled for a described v5e at C=10, K=4,
+# N=6144, the whole-d kernel fits at d=104 (windows 12.9 MB) and is refused
+# at d=112 (windows 13.9 MB; scoped allocation 16.16M against 16.00M).
+WINDOW_BUDGET = 12 * 2 ** 20
+
+
+def _payload_operands(wire):
+    """(payload dtype as the kernel reads it, scale/zero-point lanes)."""
+    codec = get_codec(wire)
+    dtype = (jnp.float32 if codec.payload_dtype == jnp.float16
+             else codec.payload_dtype)       # upcast before the kernel
+    return dtype, (int(codec.has_scale) + int(codec.has_zp)
+                   if codec.quantized else 0)
+
+
+@functools.lru_cache(maxsize=None)
+def receive_d_tile(n: int, d: int, c: int, k: int, wire=None) -> int:
+    """Coefficients a grid step of the receive kernel holds: ``d`` when the
+    whole-d kernel's windows fit :data:`WINDOW_BUDGET` (then the engine
+    runs :func:`fused_receive_apply`), else the largest multiple of 8
+    whose tiled windows do (:func:`fused_receive_apply_dtiled`)."""
+    dtype, n_meta = _payload_operands(wire)
+    p = get_codec(wire).payload_cols(d)
+    if _window_bytes(_receive_layout(n, d, c, k, p, dtype,
+                                     n_meta)) <= WINDOW_BUDGET:
+        return d
+    fits = lambda t: _window_bytes(_receive_layout(
+        n, d, c, k, d, jnp.float32, 0, t)) <= WINDOW_BUDGET
+    if not fits(8):
+        raise ValueError(f"no d tile of the receive kernel fits "
+                         f"{WINDOW_BUDGET} bytes of VMEM at C={c}, K={k}")
+    t = 8
+    while t + 8 < d and fits(t + 8):
+        t += 8
+    return t
+
+
+@functools.lru_cache(maxsize=None)
+def receive_call_bytes(n: int, d: int, c: int, k: int, wire, d_tile: int
+                       ) -> int:
+    """HBM bytes one call of the receive kernel reads and writes, as its
+    BlockSpecs move them (:func:`_moved_bytes`): the whole-d kernel for
+    ``d_tile == d``, else the d-tiled one, both passes."""
+    if d_tile != d:
+        return _moved_bytes(_receive_layout(n, d, c, k, d, jnp.float32, 0,
+                                            d_tile))
+    dtype, n_meta = _payload_operands(wire)
+    return _moved_bytes(_receive_layout(
+        n, d, c, k, get_codec(wire).payload_cols(d), dtype, n_meta))
+
+
 @functools.partial(jax.jit, static_argnames=("variant", "lam", "interpret",
                                              "wire", "defense"))
 def fused_receive_apply(last_w, last_t, fresh_w, fresh_t, cache_w, cache_t,
@@ -301,7 +454,6 @@ def fused_receive_apply(last_w, last_t, fresh_w, fresh_t, cache_w, cache_t,
     mode = _wire_mode(wire, msg_scale, msg_zp)
     if msg_w.dtype == jnp.float16:      # Mosaic on v5e cannot convert f16
         msg_w = msg_w.astype(jnp.float32)
-    blk = _node_block(n)
     row = lambda a: a.reshape(1, n)
 
     kw = dict(variant=variant, lam=lam, k_rounds=k, wire_mode=mode,
@@ -310,16 +462,14 @@ def fused_receive_apply(last_w, last_t, fresh_w, fresh_t, cache_w, cache_t,
             if m is not None and mode != "float"]
     kernel = {0: _kernel_no_meta, 1: _kernel_scale_only,
               2: _cycle_kernel}[len(meta)]
-    vec, sca, cvec = _lanes(blk, d), _lanes(blk, 1), _lanes(blk, c, d)
-    ksca = _lanes(blk, k)
+    grid, in_blocks, out_blocks = _receive_layout(n, d, c, k, p, msg_w.dtype,
+                                                  len(meta))
     n_in = 3 + len(meta)                 # index of x among the operands
     outs = pl.pallas_call(
         functools.partial(kernel, **kw),
-        grid=(pl.cdiv(n, blk),),
-        in_specs=[_lanes(blk, k, p), ksca, *[ksca] * len(meta), ksca, vec,
-                  sca, vec, sca, vec, sca, cvec, _lanes(blk, c), sca, sca],
-        out_specs=[vec, sca, vec, sca, cvec, _lanes(blk, c), sca, sca, sca,
-                   sca],
+        grid=grid,
+        in_specs=[spec for spec, _ in in_blocks],
+        out_specs=[spec for spec, _ in out_blocks],
         out_shape=[
             jax.ShapeDtypeStruct((d, n), last_w.dtype),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
@@ -338,6 +488,157 @@ def fused_receive_apply(last_w, last_t, fresh_w, fresh_t, cache_w, cache_t,
     )(msg_w.transpose(0, 2, 1), msg_t, *meta, valid, x.T, row(y),
       last_w.T, row(last_t), fresh_w.T, row(fresh_t),
       cache_w.transpose(1, 2, 0), cache_t.T, row(ptr), row(count))
+    lw, lt, fw, ft, cw, ct, ptr, cnt, gated, clipped = outs
+    return (lw.T, lt[0], fw.T, ft[0], cw.transpose(2, 0, 1), ct.T, ptr[0],
+            cnt[0], gated[0], clipped[0])
+
+
+def _dtiled_kernel(msg_w_ref, msg_t_ref, valid_ref, x_ref, y_ref,
+                  last_w_ref, last_t_ref, fw_ref, ft_ref, cw_ref, ct_ref,
+                  ptr_ref, cnt_ref, out_lw, out_lt, out_fw, out_ft, out_cw,
+                  out_ct, out_ptr, out_cnt, out_gated, out_clipped, acc_ref,
+                  eta_ref, slot_ref, *, lam: float, k_rounds: int, d: int,
+                  d_tile: int):
+    """One grid step of :func:`fused_receive_apply_dtiled`: node block i,
+    pass q, d tile j; models (d_tile, BLK), cache (C, d_tile, BLK),
+    messages (K, d_tile, BLK), per-node rows (1, BLK) or (K, BLK).
+
+    Round k's merge ``(m_k + last_k) / 2`` is known from the operands
+    alone (``last_k`` is the previous valid message), so pass 0 sums each
+    round's margin over the tiles into ``acc_ref`` (K, BLK), and pass 1
+    applies the merge, the Pegasos step with that margin, the cache write
+    and the freshest and last model, tile by tile. The per-node counters
+    are computed once, at the node block's first step, where ``eta_ref``
+    and ``slot_ref`` (the cache slot round k writes, -1 for no receive)
+    are kept for pass 1. The ops are ``_cycle_kernel``'s for the "mu"
+    variant; only the margin's summation order differs."""
+    q, j = pl.program_id(1), pl.program_id(2)
+    rounds = [slice(kk, kk + 1) for kk in range(k_rounds)]
+
+    @pl.when((q == 0) & (j == 0))
+    def _counters():
+        lt, ft, ct = last_t_ref[...], ft_ref[...], ct_ref[...]
+        ptr, cnt = ptr_ref[...], cnt_ref[...]
+        c, blk = ct.shape
+        slots = lax.broadcasted_iota(jnp.int32, (c, blk), 0)
+        for row in rounds:
+            vm = valid_ref[row, :] > 0
+            mt = msg_t_ref[row, :]
+            t = jnp.maximum(mt, lt) + 1
+            eta_ref[row, :] = 1.0 / (lam * t.astype(jnp.float32))
+            slot_ref[row, :] = jnp.where(vm, ptr % c, -1)
+            ct = jnp.where((slots == ptr % c) & vm, t, ct)
+            ft = jnp.where(vm, t, ft)
+            ptr = ptr + vm.astype(jnp.int32)
+            cnt = jnp.minimum(cnt + vm.astype(jnp.int32), c)
+            lt = jnp.where(vm, mt, lt)
+        out_lt[...] = lt
+        out_ft[...] = ft
+        out_ct[...] = ct
+        out_ptr[...] = ptr
+        out_cnt[...] = cnt
+        out_gated[...] = jnp.zeros_like(ptr)
+        out_clipped[...] = jnp.zeros_like(ptr)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def merges():
+        """``[(round, receives, merge)]`` of this tile in round order, and
+        the last model after the rounds."""
+        lw = last_w_ref[...].astype(jnp.float32)
+        out = []
+        for kk, row in enumerate(rounds):
+            vm = valid_ref[row, :] > 0
+            mw = msg_w_ref[kk].astype(jnp.float32)
+            out.append((row, vm, (mw + lw) / 2.0))
+            lw = jnp.where(vm, mw, lw)
+        return out, lw
+
+    x = x_ref[...].astype(jnp.float32)
+    y = y_ref[...].astype(jnp.float32)
+
+    @pl.when(q == 0)
+    def _margins():
+        # rows past d in the ragged last tile hold no coefficient
+        coef = lax.broadcasted_iota(jnp.int32, (d_tile, 1), 0) + j * d_tile
+        for row, _, w in merges()[0]:
+            acc_ref[row, :] += jnp.sum(jnp.where(coef < d, w * x, 0.0),
+                                       axis=0, keepdims=True)
+
+    @pl.when(q == 1)
+    def _apply():
+        fw = fw_ref[...].astype(jnp.float32)
+        rounds_w, lw = merges()
+        new = []
+        for row, vm, w in rounds_w:
+            eta = eta_ref[row, :]
+            margin = y * acc_ref[row, :]
+            decay = 1.0 - eta * lam
+            nw = decay * w + jnp.where(margin < 1.0, eta * (y * x), 0.0)
+            fw = jnp.where(vm, nw, fw)
+            new.append(nw)
+        out_lw[...] = lw.astype(out_lw.dtype)
+        out_fw[...] = fw.astype(out_fw.dtype)
+        # slot by slot, so one (d_tile, BLK) slab is live at a time; a
+        # later round wins a slot, as in the sequential cache adds
+        for s in range(cw_ref.shape[0]):
+            v = cw_ref[s].astype(jnp.float32)
+            for row, nw in zip(rounds, new):
+                v = jnp.where(slot_ref[row, :] == s, nw, v)
+            out_cw[s] = v.astype(out_cw.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("variant", "lam", "interpret",
+                                             "wire", "defense", "d_tile"))
+def fused_receive_apply_dtiled(last_w, last_t, fresh_w, fresh_t, cache_w,
+                               cache_t, ptr, count, msg_w, msg_t, valid, x,
+                               y, *, msg_scale=None, msg_zp=None, wire=None,
+                               variant: str, lam: float,
+                               interpret: bool = False,
+                               defense: str = "none", d_tile: int):
+    """:func:`fused_receive_apply` with the coefficient axis tiled, for a
+    d at which a whole node block does not fit VMEM (the engine picks it
+    by :func:`receive_d_tile`). Same operands, outputs and in-place
+    aliasing, in the same node-minor views; the grid is (node block, pass,
+    d tile) and each round's margin is summed over the tiles before any
+    coefficient changes (:func:`_dtiled_kernel`). ``d_tile``: coefficients
+    a step, a multiple of 8 (the engine's is :func:`receive_d_tile`'s);
+    the last tile may be ragged.
+
+    It implements the f32 wire, the "mu" variant (the paper's P2Pegasos)
+    and no defense; anything else is refused, not computed otherwise."""
+    n, d = last_w.shape
+    c = cache_w.shape[1]
+    k = msg_w.shape[0]
+    if (_wire_mode(wire, msg_scale, msg_zp) != "float"
+            or msg_w.dtype != jnp.float32 or variant != "mu"
+            or defense != "none"):
+        raise ValueError(
+            f"the d-tiled receive kernel (d={d}: a whole node block does not "
+            f"fit VMEM) implements the f32 wire, the 'mu' variant and no "
+            f"defense; got wire={wire!r} ({msg_w.dtype} payload), "
+            f"variant={variant!r}, defense={defense!r}")
+    if d_tile % 8:
+        raise ValueError(f"d_tile must be a multiple of 8, got {d_tile}")
+    row = lambda a: a.reshape(1, n)
+    grid, in_blocks, out_blocks = _receive_layout(n, d, c, k, d, msg_w.dtype,
+                                                  0, d_tile)
+    blk = _node_block(n)
+    outs = pl.pallas_call(
+        functools.partial(_dtiled_kernel, lam=lam, k_rounds=k, d=d,
+                          d_tile=d_tile),
+        grid=grid,
+        in_specs=[spec for spec, _ in in_blocks],
+        out_specs=[spec for spec, _ in out_blocks],
+        out_shape=[a for _, a in out_blocks],
+        scratch_shapes=[pltpu.VMEM((k, blk), jnp.float32),
+                        pltpu.VMEM((k, blk), jnp.float32),
+                        pltpu.VMEM((k, blk), jnp.int32)],
+        # the model, cache and counter state updates in place
+        input_output_aliases={5 + i: i for i in range(8)},
+        interpret=interpret,
+    )(msg_w.transpose(0, 2, 1), msg_t, valid, x.T, row(y), last_w.T,
+      row(last_t), fresh_w.T, row(fresh_t), cache_w.transpose(1, 2, 0),
+      cache_t.T, row(ptr), row(count))
     lw, lt, fw, ft, cw, ct, ptr, cnt, gated, clipped = outs
     return (lw.T, lt[0], fw.T, ft[0], cw.transpose(2, 0, 1), ct.T, ptr[0],
             cnt[0], gated[0], clipped[0])
@@ -464,7 +765,6 @@ def quantize_send(w, name: str, key_data=None, ef=None, *,
         kd = (jnp.asarray(key_data, jnp.uint32).reshape(2) if stochastic
               else jnp.zeros((2,), jnp.uint32))
 
-        from jax.experimental.pallas import tpu as pltpu
         lanes = lambda *lead: pl.BlockSpec(
             (*lead, blk), lambda i, *_: (*(0,) * len(lead), i))
         grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -290,6 +290,37 @@ def test_snapshot_parity_reference_vs_sharded():
             npt.assert_array_equal(a, b, err_msg=f"cycle {cyc}: {field}")
 
 
+def test_sharded_snapshot_refers_to_the_carry():
+    """``snapshot_from_carry`` builds nothing on the device: each leaf is
+    a lane of the carry (freshest model 2-3, cache 4, 5 and 7, clock 13);
+    that lanes 2-3 hold ``cache.freshest`` is pinned by the parity tests
+    above."""
+    carry = tuple(jnp.full((2,), i) for i in range(14))
+    snap = serving.snapshot_from_carry(carry)
+    lanes = [next(i for i, a in enumerate(carry) if a is leaf)
+             for leaf in snap]
+    assert lanes == [4, 5, 7, 2, 3, 13]
+
+
+def test_last_hook_runs_without_the_payload_buffer():
+    """The sharded engine frees the state the last snapshot does not read
+    (models, payload buffer) before its last hook, so that what the hook
+    allocates stays under the run's peak: the (D, N, d) buffer is live at
+    every hook but the last."""
+    cfg = _sim_setup()[0]
+    from repro.configs.gossip_linear import with_failure_scenario
+    D = with_failure_scenario(cfg, "extreme").delay_max_cycles
+    seen = {}
+
+    def hook(cycle, snap):
+        seen[cycle] = any(a.shape == (D, N_NODES, DIM)
+                          for a in jax.live_arrays())
+
+    res = _run("sharded", serve_hook=hook, scenario="extreme")
+    assert D > 1 and len(res.cycles) > 1
+    assert seen == {c: c != res.cycles[-1] for c in res.cycles}
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_served_answers_reproducible_and_kernel_invariant(use_kernel):
     """Same seed + same submission order -> bitwise identical answers, on

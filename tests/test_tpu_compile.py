@@ -1,11 +1,13 @@
 """Compile rehearsal: the main path's Pallas kernels for a described TPU v5e.
 
-Nothing runs: each test lowers and compiles one kernel at the size
-``chip_smoke.py`` drives (the paper's SPAMBASE geometry, d=57, C=10, K=4,
-at N=10^6 nodes) for a v5e chip that is described, not attached, and checks
-that the compiler put the Pallas kernel in (``tpu_custom_call``) and that
-the program fits one chip's 16 GB. This catches tiling and VMEM refusals,
-which interpret mode cannot see, at no chip time.
+Nothing runs: each test lowers and compiles one kernel for a v5e chip that
+is described, not attached, and checks that the compiler put the Pallas
+kernel in (``tpu_custom_call``) and that the program fits one chip's 16 GB.
+The kernels are compiled at the size ``chip_smoke.py`` drives (the paper's
+SPAMBASE geometry, d=57, C=10, K=4, at N=10^6 nodes), and the d-tiled
+receive kernel at the paper's Reuters geometry (d=9947) on the 6144 nodes of
+the benchmark's Reuters configuration. This catches tiling and VMEM
+refusals, which interpret mode cannot see, at no chip time.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library.
@@ -18,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 N, D, C, K = 1_000_000, 57, 10, 4        # chip_smoke's protocol phase
+N_REUTERS, D_REUTERS = 6144, 9947        # configs/reuters-f32-6k.json
 M = 4096                                 # chip_smoke's serving batch
 HBM_BYTES = 16 * 10 ** 9                 # one v5e chip
 
@@ -81,6 +84,26 @@ def test_fused_receive_apply_compiles(wire, one_chip):
              ((N, C, D), f32), ((N, C), i32), ((N,), i32), ((N,), i32),
              _payload(wire), ((K, N), i32), ((K, N), i32), ((N, D), f32),
              ((N,), f32), one_chip=one_chip, **meta)
+
+
+def test_fused_receive_apply_dtiled_compiles(one_chip):
+    from repro.kernels.gossip_cycle import (fused_receive_apply_dtiled,
+                                            receive_d_tile)
+
+    n, d = N_REUTERS, D_REUTERS
+    tile = receive_d_tile(n, d, C, K)
+    assert tile < d                           # the engine's choice here
+
+    def receive(*args):
+        return fused_receive_apply_dtiled(*args, variant="mu", lam=1e-4,
+                                          d_tile=tile)
+
+    f32, i32 = jnp.float32, jnp.int32
+    _compile(jax.jit(receive),
+             ((n, d), f32), ((n,), i32), ((n, d), f32), ((n,), i32),
+             ((n, C, d), f32), ((n, C), i32), ((n,), i32), ((n,), i32),
+             ((K, n, d), f32), ((K, n), i32), ((K, n), i32), ((n, d), f32),
+             ((n,), f32), one_chip=one_chip)
 
 
 @pytest.mark.parametrize("wire", ["int8", "int8_sr", "int4_ef"])
